@@ -48,8 +48,13 @@ LARGE_SIZES = [256 << 10, 1 << 20, 4 << 20]
 LARGE_LOSSES = [0.0, 0.02]
 LARGE_RANKS = 8
 
+# a transformer-block-shaped gradient pytree, ~6.1 MiB of f32
+GRAD_SHAPES = dict(wq=(1024, 256), wk=(1024, 256), wv=(1024, 256),
+                   wo=(256, 1024), w_up=(256, 1024), w_down=(1024, 192),
+                   embed=(4096, 24), norm=(1024,))
 
-def _large_cfg() -> mpi.MpiConfig:
+
+def large_cfg() -> mpi.MpiConfig:
     """Wire sized for multi-MiB vectors: big frames, deep SLMP windows,
     wide NIC ingress batches, 128 KiB rendezvous segments, 8 slot
     credits — the configuration the gradient-sync numbers are quoted at."""
@@ -57,7 +62,7 @@ def _large_cfg() -> mpi.MpiConfig:
                          n_rdv_slots=8, coll_seg_bytes=128 << 10)
 
 
-def _dtypes():
+def fig10_datatypes():
     reg = mpi.DatatypeRegistry()
     return reg, dict(
         simple=reg.register(ddtlib.simple_ddt(), count=1024, name="simple"),
@@ -77,7 +82,7 @@ def _one_transfer(comm: mpi.Communicator, cid: int, mem, buf,
 
 
 def _overlap_sweep(records: List[dict]) -> None:
-    reg, ids = _dtypes()
+    reg, ids = fig10_datatypes()
     comm = mpi.Communicator(2, registry=reg, seed=0)
     rng = np.random.default_rng(0)
     for name, cid in ids.items():
@@ -233,7 +238,7 @@ def _allreduce_large_sweep(records: List[dict]) -> None:
     rd ships ⌈log₂ n⌉ full vectors per rank where Rabenseifner ships
     ~2·(n−1)/n of one — and at the largest size that bandwidth gap must
     show up in modeled ticks too (asserted)."""
-    comm = mpi.Communicator(LARGE_RANKS, seed=0, cfg=_large_cfg(),
+    comm = mpi.Communicator(LARGE_RANKS, seed=0, cfg=large_cfg(),
                             link_cfg=LinkConfig(latency=1))
     rng = np.random.default_rng(21)
     for loss in LARGE_LOSSES:
@@ -283,13 +288,10 @@ def _grad_allreduce(records: List[dict]) -> None:
     step.  At loss=0 the transfer must hide almost completely."""
     from repro.train.manual_dp import FabricGradSync
     n = 4
-    comm = mpi.Communicator(n, seed=7, cfg=_large_cfg(),
+    comm = mpi.Communicator(n, seed=7, cfg=large_cfg(),
                             link_cfg=LinkConfig(latency=1))
     rng = np.random.default_rng(33)
-    # a transformer-block-shaped gradient pytree, ~4.25 MiB of f32
-    shapes = dict(wq=(1024, 256), wk=(1024, 256), wv=(1024, 256),
-                  wo=(256, 1024), w_up=(256, 1024), w_down=(1024, 192),
-                  embed=(4096, 24), norm=(1024,))
+    shapes = GRAD_SHAPES
     grads = [{k: rng.normal(size=s).astype(np.float32)
               for k, s in shapes.items()} for _ in range(n)]
     ref_mean = {k: np.mean(np.stack([g[k] for g in grads]), axis=0,

@@ -38,6 +38,8 @@ def main() -> None:
     from benchmarks import (bench_ddt, bench_fabric, bench_kernels,
                             bench_latency, bench_mpi, bench_pingpong,
                             bench_slmp)
+    from repro import compile_cache
+    compile_cache.configure()
     suites = [
         ("fig7_pingpong", bench_pingpong.run),
         ("fig8_slmp", bench_slmp.run),

@@ -4,12 +4,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.checksum import checksum as _k
 from repro.kernels.checksum import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def internet_checksum(data: jax.Array, lengths: jax.Array, *, start: int,
@@ -24,5 +21,5 @@ def internet_checksum(data: jax.Array, lengths: jax.Array, *, start: int,
         data = jnp.pad(data, ((0, pad), (0, 0)))
         lengths = jnp.pad(lengths, (0, pad), constant_values=start)
     out = _k.checksum_pallas(data, lengths, start=start, block_n=block_n,
-                             interpret=_interpret())
+                             interpret=kernels.interpret())
     return out[:n]

@@ -22,8 +22,13 @@ VPU).  Exactly one source block contributes per element, so ``+=`` across
 the grid's S dimension reconstructs the gather exactly (zero is the
 additive identity for the masked lanes in every dtype).
 
-VMEM budget per step: BI*4 + BS*esize + BI*BS*esize bytes for the broadcast
-compare; defaults (BI=512, BS=512, f32) ≈ 1.05 MiB — comfortably inside
+The kernel body only ever sees 32-bit elements: Mosaic reduces int32 and
+f32 but not unsigned or 8/16-bit vectors, so the wrapper widens narrower
+sources (the NIC's uint8 payloads among them) to int32/f32 and reinterprets
+uint32 as int32, then narrows the result back — both exact.
+
+VMEM budget per step: BI*4 + BS*4 + BI*BS*4 bytes for the broadcast
+compare; defaults (BI=512, BS=512) ≈ 1.05 MiB — comfortably inside
 16 MiB VMEM with double buffering.
 """
 from __future__ import annotations
@@ -32,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_I = 512
@@ -52,9 +58,7 @@ def _gather_kernel(idx_ref, src_ref, out_ref, *, block_s: int, fill):
     contrib = jnp.where(hit, jnp.broadcast_to(src.reshape(1, block_s),
                                               (bi, block_s)),
                         jnp.zeros((), dtype))
-    partial = contrib.sum(axis=1, dtype=jnp.float32) if \
-        jnp.issubdtype(dtype, jnp.floating) else contrib.sum(axis=1)
-    partial = partial.astype(dtype).reshape(1, bi)
+    partial = contrib.sum(axis=1).reshape(1, bi)
 
     @pl.when(s_blk == 0)
     def _init():
@@ -79,6 +83,13 @@ def ddt_gather_pallas(src: jax.Array, idx: jax.Array, *, fill=0,
     (s,) = src.shape
     (i,) = idx.shape
     assert s % block_s == 0 and i % block_i == 0, (s, i, block_s, block_i)
+    dtype = src.dtype
+    if dtype.itemsize < 4:               # widen: exact for every narrow type
+        src = src.astype(jnp.float32 if jnp.issubdtype(dtype, jnp.floating)
+                         else jnp.int32)
+    elif dtype == jnp.uint32:            # reinterpret the bits as int32
+        src = jax.lax.bitcast_convert_type(src, jnp.int32)
+        fill = int(np.asarray(fill, np.uint32).view(np.int32))
     grid = (i // block_i, s // block_s)
     kernel = functools.partial(_gather_kernel, block_s=block_s, fill=fill)
     out = pl.pallas_call(
@@ -92,4 +103,6 @@ def ddt_gather_pallas(src: jax.Array, idx: jax.Array, *, fill=0,
         out_shape=jax.ShapeDtypeStruct((1, i), src.dtype),
         interpret=interpret,
     )(idx.reshape(1, i), src.reshape(1, s))
-    return out.reshape(i)
+    if dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(out.reshape(i), dtype)
+    return out.reshape(i).astype(dtype)

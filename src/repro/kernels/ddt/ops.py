@@ -15,12 +15,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.ddt import ddt as _k
 from repro.kernels.ddt import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def gather(src: jax.Array, idx: jax.Array, *, fill=0,
@@ -38,7 +35,7 @@ def gather(src: jax.Array, idx: jax.Array, *, fill=0,
     if pad_i:
         idx = jnp.pad(idx, (0, pad_i), constant_values=-1)
     out = _k.ddt_gather_pallas(src, idx, fill=fill, block_i=block_i,
-                               block_s=block_s, interpret=_interpret())
+                               block_s=block_s, interpret=kernels.interpret())
     return out[:i]
 
 

@@ -28,12 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:                                     # TPU scratch memory spaces
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                        # pragma: no cover - CPU fallback
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 512
@@ -115,12 +110,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     kernel = functools.partial(
         _flash_kernel, causal=causal, window=window, block_q=bq,
         block_k=bk, nk=nk, scale=scale)
-    if _VMEM is not None:
-        scratch = [_VMEM((bq,), jnp.float32), _VMEM((bq,), jnp.float32),
-                   _VMEM((bq, d), jnp.float32)]
-    else:                                # pragma: no cover
-        scratch = [jax.ShapeDtypeStruct((bq,), jnp.float32)] * 2 + \
-            [jax.ShapeDtypeStruct((bq, d), jnp.float32)]
+    scratch = [pltpu.VMEM((bq,), jnp.float32), pltpu.VMEM((bq,), jnp.float32),
+               pltpu.VMEM((bq, d), jnp.float32)]
     return pl.pallas_call(
         kernel,
         grid=(h, nq, nk),

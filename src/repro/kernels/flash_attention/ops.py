@@ -6,15 +6,11 @@ GQA group), pads sequence to kernel blocks, and dispatches Pallas
 (interpret on CPU) or the jnp oracle."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention import flash_attention as _k
 from repro.kernels.flash_attention import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -46,6 +42,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         of = _k.flash_attention_pallas(qf, kf, vf, causal=causal,
                                        window=window, block_q=bq,
                                        block_k=bk,
-                                       interpret=_interpret())
+                                       interpret=kernels.interpret())
         of = of[:, :sq]
     return of.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -6,15 +6,19 @@ The hardware matcher inspects one 32-bit word per rule; a ruleset is
 VPU (bitwise ops + compares, no MXU):
 
   grid:   (N // BLOCK_N,)
-  VMEM:   words  (BLOCK_N, W) uint32   -- the packet word view
-          rules  (C, 4, 4)    uint32   -- replicated to every block
-          modes  (1, C)       int32
-  out:    matched, eom  (BLOCK_N, C) int32
+  SMEM:   rules  (C * 16,)    int32    -- (C, 4, 4) table, flattened
+          modes  (C,)         int32
+  VMEM:   words  (W, BLOCK_N) int32    -- the packet word view, transposed
+                                          so packets run along the lanes
+  out:    matched, eom  (C, BLOCK_N) int32
 
-Word selection (``words[:, idx[c, r]]``) is done with a broadcasted-iota
-compare-and-sum instead of a dynamic gather: the index is a scalar per
-(context, rule), so ``sum(where(iota == idx, words, 0), axis=-1)`` is a
-single masked row-reduction — the idiomatic Mosaic-friendly form.
+The rule table lives in SMEM because every rule field is read as a scalar.
+Word selection (``words[idx[c, r], :]``) is a broadcasted-iota
+compare-and-sum over the sublane axis instead of a dynamic gather: exactly
+one word row matches, so the int32 sum returns that word's bit pattern.
+Mosaic has no unsigned reductions or compares, so the u32 words travel as
+their int32 bit patterns and the unsigned range test ``start <= v <= end``
+runs as a signed compare after flipping the sign bit of both sides.
 """
 from __future__ import annotations
 
@@ -23,39 +27,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_N = 128
+_SIGN = -(1 << 31)              # int32 sign bit: u32 order == (x ^ _SIGN) order
 
 
-def _matcher_kernel(words_ref, rules_ref, modes_ref, matched_ref, eom_ref,
+def _matcher_kernel(rules_ref, modes_ref, words_ref, matched_ref, eom_ref,
                     *, n_ctx: int):
-    words = words_ref[...]                        # (BN, W) uint32
-    rules = rules_ref[...]                        # (C, 4, 4) uint32
-    modes = modes_ref[...]                        # (1, C) int32
-    bn, w = words.shape
-    w_iota = jax.lax.broadcasted_iota(jnp.uint32, (bn, w), 1)
+    words = words_ref[...]                        # (W, BN) int32
+    w, _ = words.shape
+    w_iota = jax.lax.broadcasted_iota(jnp.int32, words.shape, 0)
 
-    match_cols = []
-    eom_cols = []
     for c in range(n_ctx):
         oks = []
         for r in range(4):
-            idx = rules[c, r, 0]
-            mask = rules[c, r, 1]
-            start = rules[c, r, 2]
-            end = rules[c, r, 3]
-            # select word `idx` from each packet (exactly one lane matches)
-            sel = jnp.sum(jnp.where(w_iota == idx, words, jnp.uint32(0)),
-                          axis=1)
-            v = sel & mask
+            base = (c * 4 + r) * 4
+            idx = jnp.clip(rules_ref[base], 0, w - 1)
+            mask = rules_ref[base + 1]
+            start = rules_ref[base + 2] ^ _SIGN
+            end = rules_ref[base + 3] ^ _SIGN
+            # select word `idx` of every packet (exactly one row matches)
+            sel = jnp.sum(jnp.where(w_iota == idx, words, 0), axis=0,
+                          keepdims=True)          # (1, BN)
+            v = (sel & mask) ^ _SIGN
             oks.append((v >= start) & (v <= end))
         and_mode = oks[0] & oks[1] & oks[2]
         or_mode = oks[0] | oks[1] | oks[2]
-        is_and = modes[0, c] == 0
-        match_cols.append(jnp.where(is_and, and_mode, or_mode))
-        eom_cols.append(oks[3])
-    matched_ref[...] = jnp.stack(match_cols, axis=1).astype(jnp.int32)
-    eom_ref[...] = jnp.stack(eom_cols, axis=1).astype(jnp.int32)
+        is_and = (modes_ref[c] == 0).astype(jnp.int32)
+        matched_ref[c:c + 1, :] = (and_mode.astype(jnp.int32) * is_and
+                                   + or_mode.astype(jnp.int32) * (1 - is_and))
+        eom_ref[c:c + 1, :] = oks[3].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -69,22 +71,17 @@ def match_pallas(words: jax.Array, rules: jax.Array, modes: jax.Array,
     n, w = words.shape
     c = rules.shape[0]
     assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
-    out_shape = [jax.ShapeDtypeStruct((n, c), jnp.int32)] * 2
+    words_t = jax.lax.bitcast_convert_type(words, jnp.int32).T
+    rules_flat = jax.lax.bitcast_convert_type(rules, jnp.int32).reshape(-1)
+    out_shape = [jax.ShapeDtypeStruct((c, n), jnp.int32)] * 2
     kernel = functools.partial(_matcher_kernel, n_ctx=c)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     matched, eom = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
-            pl.BlockSpec((c, 4, 4), lambda i: (0, 0, 0)),
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, c), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, c), lambda i: (i, 0)),
-        ],
+        grid=(n // block_n,),
+        in_specs=[smem, smem, pl.BlockSpec((w, block_n), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((c, block_n), lambda i: (0, i))] * 2,
         out_shape=out_shape,
         interpret=interpret,
-    )(words, rules, modes.reshape(1, -1))
-    return matched, eom
+    )(rules_flat, modes.astype(jnp.int32), words_t)
+    return matched.T, eom.T

@@ -1,20 +1,17 @@
 """Jit'd public wrapper for the matching-engine kernel.
 
 Pads the packet dimension to the kernel block, dispatches to the Pallas
-kernel (``interpret=True`` on CPU — the kernel body executes in Python for
-validation; compiled Mosaic on real TPU) or to the jnp reference.
+kernel (interpret mode on CPU — the kernel body executes as jnp ops for
+validation; compiled Mosaic on TPU) or to the jnp reference.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.matcher import matcher as _k
 from repro.kernels.matcher import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def match(words: jax.Array, rules: jax.Array, modes: jax.Array,
@@ -32,5 +29,5 @@ def match(words: jax.Array, rules: jax.Array, modes: jax.Array,
     if pad:
         words = jnp.pad(words, ((0, pad), (0, 0)))
     matched, eom = _k.match_pallas(words, rules, modes, block_n=block_n,
-                                   interpret=_interpret())
+                                   interpret=kernels.interpret())
     return matched[:n].astype(bool), eom[:n].astype(bool)

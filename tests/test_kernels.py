@@ -16,7 +16,7 @@ from repro.kernels.matcher.ref import match_ref
 
 # ------------------------------------------------------------- ddt gather
 @pytest.mark.parametrize("dtype", [np.float32, np.int32, np.uint8,
-                                   "bfloat16"])
+                                   np.uint32, "bfloat16"])
 @pytest.mark.parametrize("s,i", [(16, 16), (100, 777), (1000, 333),
                                  (513, 1025), (2048, 64)])
 def test_ddt_gather_matches_ref(dtype, s, i):
@@ -134,6 +134,33 @@ def test_matcher_or_mode():
     for uk in (False, True):
         mm, _ = mops.match(b.words(), t.rules, t.modes, use_kernel=uk)
         assert bool(mm[0, 0]) and bool(mm[1, 0])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_matcher_kernel_full_u32_range(seed):
+    """Words, masks and ranges with the top bit set (the kernel compares
+    unsigned values through int32), word indices past the packet (clipped
+    like the reference), and both combiner modes."""
+    rng = np.random.default_rng(seed)
+    words = rng.integers(0, 1 << 32, size=(130, pkt.WORDS), dtype=np.uint64)
+    rules = rng.integers(0, 1 << 32, size=(3, 4, 4), dtype=np.uint64)
+    rules[:, :, 0] = rng.integers(0, pkt.WORDS + 8, size=(3, 4))
+    # half the ranges bracket the masked word so matches actually happen
+    w_sel = words[0, np.minimum(rules[:, :, 0], pkt.WORDS - 1)]
+    v = w_sel & rules[:, :, 1]
+    near = rng.random((3, 4)) < 0.5
+    near[0] = True                  # context 0 matches packet 0
+    rules[:, :, 2] = np.where(near, v // 2, rules[:, :, 2])
+    rules[:, :, 3] = np.where(near, v + (((1 << 32) - 1 - v) // 2),
+                              rules[:, :, 3])
+    words = jnp.asarray(words.astype(np.uint32))
+    rules = jnp.asarray(rules.astype(np.uint32))
+    modes = jnp.asarray(rng.integers(0, 2, size=3).astype(np.int32))
+    mk, ek = mops.match(words, rules, modes, use_kernel=True)
+    mr, er = match_ref(words, rules, modes)
+    np.testing.assert_array_equal(np.asarray(mk), np.asarray(mr))
+    np.testing.assert_array_equal(np.asarray(ek), np.asarray(er))
+    assert bool(mk[0, 0]) and bool(ek[0, 0])
 
 
 # -------------------------------------------------------- flash attention
