@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import alloc as palloc
 from repro.core import handlers as H
 from repro.core import her as herlib
@@ -280,14 +281,16 @@ class SpinNIC:
         msg_id table — the host posts the receive to the NIC *before*
         telling the sender to fire, so a recycled DMA region only accepts
         frames of its current occupant."""
-        return dataclasses.replace(
-            state, expect=state.expect.at[idx].set(
-                jnp.uint32(msg_id)))
+        with obs.span("nic.write"):
+            return dataclasses.replace(
+                state, expect=state.expect.at[idx].set(
+                    jnp.uint32(msg_id)))
 
     def read_host(self, state: NICState, base: int, nbytes: int
                   ) -> np.ndarray:
         """Host read of the DMA window (the /dev/pspin0 mmap view)."""
-        return np.asarray(state.host[base:base + nbytes])
+        with obs.span("d2h.host_window"):
+            return np.asarray(state.host[base:base + nbytes])
 
     def pop_counters(self, state: NICState, queue: int
                      ) -> Tuple[np.ndarray, NICState]:
@@ -297,15 +300,18 @@ class SpinNIC:
         count cleared — a second pop yields nothing until handlers push
         again (a real FIFO drain, not a peek).
         """
-        cnt = int(state.counter_count[queue])
+        with obs.span("d2h.completions"):
+            cnt = int(state.counter_count[queue])
         if cnt == 0:
             # nothing pushed since the last drain: skip the device
             # round-trips (this runs after every non-idle fabric tick)
             return np.zeros(0, np.int32), state
-        vals = np.asarray(state.counters[queue])
+        with obs.span("d2h.completions"):
+            vals = np.asarray(state.counters[queue])
         start = max(0, cnt - H.COUNTER_QUEUE_LEN)   # older entries overwritten
         drained = np.array([vals[(start + i) % H.COUNTER_QUEUE_LEN]
                             for i in range(cnt - start)], np.int32)
-        new_state = dataclasses.replace(
-            state, counter_count=state.counter_count.at[queue].set(0))
+        with obs.span("nic.write"):
+            new_state = dataclasses.replace(
+                state, counter_count=state.counter_count.at[queue].set(0))
         return drained, new_state

@@ -60,6 +60,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.mpi.communicator import COLL_TAG_BASE, Communicator
 from repro.mpi.engine import Request
 
@@ -297,25 +298,26 @@ class Plan:
     def _step(self, key: tuple, req: Request) -> None:
         if self.finished:
             return
-        self.pending.discard(key)
-        if req.error:
-            self._abort(req.error)
-            return
-        deliver = True
-        if key[0] in ("sg", "rg"):
-            key = self._seg_step(key)
-            deliver = key is not None
-        if deliver:
-            self._depth += 1
-            try:
-                self.on_step(key, req)
-            finally:
-                self._depth -= 1
-        # drain only at depth 0: a synchronously-completing self-send must
-        # not finish the plan while an outer start()/on_step() is still
-        # posting the rest of its wave
-        if not self.pending and not self.finished and self._depth == 0:
-            self.on_drain()
+        with obs.span("mpi.plan"):
+            self.pending.discard(key)
+            if req.error:
+                self._abort(req.error)
+                return
+            deliver = True
+            if key[0] in ("sg", "rg"):
+                key = self._seg_step(key)
+                deliver = key is not None
+            if deliver:
+                self._depth += 1
+                try:
+                    self.on_step(key, req)
+                finally:
+                    self._depth -= 1
+            # drain only at depth 0: a synchronously-completing self-send
+            # must not finish the plan while an outer start()/on_step() is
+            # still posting the rest of its wave
+            if not self.pending and not self.finished and self._depth == 0:
+                self.on_drain()
 
     def _seg_step(self, key: tuple) -> Optional[tuple]:
         """One segment of a segmented plan message completed: land receive

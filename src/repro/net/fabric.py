@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import packet as pkt
 from repro.net import link as linklib
 from repro.net.node import Node
@@ -100,26 +101,29 @@ class Fabric:
         against the node matrix in one compare."""
         if not frames:
             return
-        dst6 = np.stack([f[pkt.ETH_DST:pkt.ETH_DST + 6] for f in frames])
-        hit = (dst6[:, None, :] == self._mac_mat[None, :, :]).all(-1)
-        dest = hit.argmax(1)
-        ok = hit.any(1)
-        self.unroutable += int((~ok).sum())
-        for i in np.flatnonzero(ok):
-            outbound[dest[i]].append(frames[i])
+        with obs.span("fabric.route"):
+            dst6 = np.stack([f[pkt.ETH_DST:pkt.ETH_DST + 6] for f in frames])
+            hit = (dst6[:, None, :] == self._mac_mat[None, :, :]).all(-1)
+            dest = hit.argmax(1)
+            ok = hit.any(1)
+            self.unroutable += int((~ok).sum())
+            for i in np.flatnonzero(ok):
+                outbound[dest[i]].append(frames[i])
 
     def _tick_batched(self) -> None:
         now = self.now
-        n_nodes = len(self.nodes)
-        self._stack, ing = _pop_all(self._cfg0, self._batch0,
-                                    self._stack, now)
+        with obs.span("link.pop"):
+            self._stack, ing = _pop_all(self._cfg0, self._batch0,
+                                        self._stack, now)
         # one host sync for the whole fabric: materialize the delivered
         # batches as numpy (a few tens of KB) — per-node numpy slices are
         # free, where N eager device slices would each pay a dispatch
-        valid = np.asarray(ing.valid)
+        with obs.span("d2h.ingress"):
+            valid = np.asarray(ing.valid)
         busy = valid.any(1)
         if busy.any():
-            data, length = np.asarray(ing.data), np.asarray(ing.length)
+            with obs.span("d2h.ingress"):
+                data, length = np.asarray(ing.data), np.asarray(ing.length)
         outbound: List[List[np.ndarray]] = [[] for _ in self.nodes]
         for i, node in enumerate(self.nodes):
             if busy[i]:
@@ -138,38 +142,43 @@ class Fabric:
         if not any(counts):
             return
         n_nodes = len(self.nodes)
-        p = 1 << max(0, (max(counts) - 1).bit_length())
-        data = np.zeros((n_nodes, p, pkt.MTU), np.uint8)
-        length = np.zeros((n_nodes, p), np.int32)
-        ok = np.zeros((n_nodes, p), bool)
-        for j, frames in enumerate(outbound):
-            for k, f in enumerate(frames):
-                data[j, k, :len(f)] = f
-                length[j, k] = len(f)
-                ok[j, k] = True
-        self.key, sub = jax.random.split(self.key)
-        keys = jax.random.split(sub, n_nodes)
-        self._stack = _push_all(
-            self._cfg0, self._stack, keys,
-            pkt.PacketBatch(jnp.asarray(data), jnp.asarray(length),
-                            jnp.asarray(ok)), self.now)
+        with obs.span("fabric.pack"):
+            p = 1 << max(0, (max(counts) - 1).bit_length())
+            data = np.zeros((n_nodes, p, pkt.MTU), np.uint8)
+            length = np.zeros((n_nodes, p), np.int32)
+            ok = np.zeros((n_nodes, p), bool)
+            for j, frames in enumerate(outbound):
+                for k, f in enumerate(frames):
+                    data[j, k, :len(f)] = f
+                    length[j, k] = len(f)
+                    ok[j, k] = True
+        with obs.span("link.push"):
+            self.key, sub = jax.random.split(self.key)
+            keys = jax.random.split(sub, n_nodes)
+            self._stack = _push_all(
+                self._cfg0, self._stack, keys,
+                pkt.PacketBatch(jnp.asarray(data), jnp.asarray(length),
+                                jnp.asarray(ok)), self.now)
 
     def _tick_loop(self) -> None:
         """Per-link fallback for heterogeneous link configs/batches."""
         now = self.now
         outbound: List[List[np.ndarray]] = [[] for _ in self.nodes]
         for i, node in enumerate(self.nodes):
-            self.link_states[i], ingress = self.links[i].pop(
-                self.link_states[i], now, node.batch)
+            with obs.span("link.pop"):
+                self.link_states[i], ingress = self.links[i].pop(
+                    self.link_states[i], now, node.batch)
             frames = node.tick(ingress, now)
             self._route(frames, outbound)
         for j, frames in enumerate(outbound):
             if not frames:
                 continue
             n = 1 << max(0, (len(frames) - 1).bit_length())
-            self.key, sub = jax.random.split(self.key)
-            self.link_states[j] = self.links[j].push(
-                self.link_states[j], sub, pkt.stack_frames(frames, n=n), now)
+            with obs.span("link.push"):
+                self.key, sub = jax.random.split(self.key)
+                self.link_states[j] = self.links[j].push(
+                    self.link_states[j], sub, pkt.stack_frames(frames, n=n),
+                    now)
 
     def run(self, max_ticks: int = 10_000, until=None) -> int:
         """Tick until ``until()`` (default: every node's engines done and
@@ -211,14 +220,16 @@ class Fabric:
         return self.link_states
 
     def link_stats(self) -> List[dict]:
-        if self._uniform:
-            # one transfer per counter for the whole fabric
-            names = ("pushed", "lost", "overflowed", "duplicated",
-                     "reordered", "delivered", "deferred")
-            cols = {k: np.asarray(getattr(self._stack, k)) for k in names}
-            return [{k: int(cols[k][i]) for k in names}
-                    for i in range(len(self.nodes))]
-        return [l.stats(s) for l, s in zip(self.links, self.link_states)]
+        with obs.span("d2h.link_stats"):
+            if self._uniform:
+                # one transfer per counter for the whole fabric
+                names = ("pushed", "lost", "overflowed", "duplicated",
+                         "reordered", "delivered", "deferred")
+                cols = {k: np.asarray(getattr(self._stack, k))
+                        for k in names}
+                return [{k: int(cols[k][i]) for k in names}
+                        for i in range(len(self.nodes))]
+            return [l.stats(s) for l, s in zip(self.links, self.link_states)]
 
     def stats(self) -> dict:
         """Fabric-wide health: unroutable frames (frames whose destination

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import handlers as H
 from repro.core import packet as pkt
 from repro.core import slmp
@@ -212,26 +213,34 @@ class Node:
         reads), and the jitted step costs the same whether the batch is
         empty or full — skipping it is what makes a mostly-idle fabric
         tick cheap.  Host engines still poll (timers, retransmits)."""
+        return self._poll(now)
+
+    def _poll(self, now: int) -> List[np.ndarray]:
         out: List[np.ndarray] = []
-        for e in self.engines:
-            out.extend(e.poll(now))
+        with obs.span("engine.poll"):
+            for e in self.engines:
+                out.extend(e.poll(now))
         return out
 
     def tick(self, ingress: pkt.PacketBatch, now: int) -> List[np.ndarray]:
         """Advance one tick: run the NIC on the delivered ingress batch,
         hand host-path frames and completions to the engines, and return
         every frame this node puts on the wire."""
-        self.state, egress, to_host = self.nic.step(self.state, ingress)
+        with obs.span("nic.step"):
+            self.state, egress, to_host = self.nic.step(self.state, ingress)
 
         # host datapath: deliver non-matching frames to the engines
-        th_valid = np.asarray(to_host.valid)
+        with obs.span("d2h.to_host"):
+            th_valid = np.asarray(to_host.valid)
         if th_valid.any():
-            data = np.asarray(to_host.data)
-            lens = np.asarray(to_host.length)
+            with obs.span("d2h.to_host"):
+                data = np.asarray(to_host.data)
+                lens = np.asarray(to_host.length)
             host_frames = [data[i, :lens[i]].copy()
                            for i in np.flatnonzero(th_valid)]
-            for e in self.engines:
-                e.on_host_frames(host_frames, now)
+            with obs.span("engine.frames"):
+                for e in self.engines:
+                    e.on_host_frames(host_frames, now)
 
         # completion notifications
         if self._completes:
@@ -239,19 +248,21 @@ class Node:
                                                      slmp.COMPLETION_QUEUE)
             if len(comp):
                 self.completions.extend(int(c) for c in comp)
-                for e in self.engines:
-                    e.on_completions(comp, now)
+                with obs.span("engine.completions"):
+                    for e in self.engines:
+                        e.on_completions(comp, now)
 
         # outbound = handler egress + engine-generated frames
         out: List[np.ndarray] = []
-        eg_valid = np.asarray(egress.valid)
+        with obs.span("d2h.egress"):
+            eg_valid = np.asarray(egress.valid)
         if eg_valid.any():
-            data = np.asarray(egress.data)
-            lens = np.asarray(egress.length)
+            with obs.span("d2h.egress"):
+                data = np.asarray(egress.data)
+                lens = np.asarray(egress.length)
             out.extend(data[i, :lens[i]].copy()
                        for i in np.flatnonzero(eg_valid))
-        for e in self.engines:
-            out.extend(e.poll(now))
+        out.extend(self._poll(now))
         return out
 
     @property
