@@ -307,11 +307,23 @@ class SpinNIC:
             # round-trips (this runs after every non-idle fabric tick)
             return np.zeros(0, np.int32), state
         with obs.span("d2h.completions"):
-            vals = np.asarray(state.counters[queue])
-        start = max(0, cnt - H.COUNTER_QUEUE_LEN)   # older entries overwritten
-        drained = np.array([vals[(start + i) % H.COUNTER_QUEUE_LEN]
-                            for i in range(cnt - start)], np.int32)
+            ring = np.asarray(state.counters[queue])
+        return drained(ring, cnt), self.clear_counters(state, queue)
+
+    def clear_counters(self, state: NICState, queue: int) -> NICState:
+        """Host MMIO: reset a counter FIFO's count once it is drained."""
         with obs.span("nic.write"):
-            new_state = dataclasses.replace(
+            return dataclasses.replace(
                 state, counter_count=state.counter_count.at[queue].set(0))
-        return drained, new_state
+
+
+def drained(ring: np.ndarray, count: int) -> np.ndarray:
+    """The values a drain of one counter FIFO returns, oldest first.
+
+    ``ring`` is a host copy of the queue's ``(COUNTER_QUEUE_LEN,)`` ring
+    and ``count`` the pushes since the last drain; past the ring's length
+    the oldest entries were overwritten and are lost.
+    """
+    start = max(0, count - H.COUNTER_QUEUE_LEN)
+    idx = np.arange(start, count) % H.COUNTER_QUEUE_LEN
+    return ring[idx].astype(np.int32)

@@ -165,6 +165,13 @@ class PingPongClient(HostEngine):
         self.timeouts = snap["timeouts"]
 
 
+def _live_frames(batch: pkt.PacketBatch) -> List[np.ndarray]:
+    """The valid frames of a host copy of a batch, each trimmed to its
+    length, in slot order."""
+    return [batch.data[i, :batch.length[i]].copy()
+            for i in np.flatnonzero(batch.valid)]
+
+
 class Node:
     """One endpoint of the fabric: NIC + host engines + a MAC address.
 
@@ -190,9 +197,9 @@ class Node:
         self.nic = nic
         contexts = nic.contexts
         self.batch = nic.batch
-        # any installed handler may push_counter; skip the per-tick FIFO
-        # drain (a blocking device read) only when no context runs handlers
-        # at all (null-context sender/client nodes — the hot-loop case)
+        # any installed handler may push_counter; leave the completion FIFO
+        # out of each step's read and drain only when no context runs
+        # handlers at all (null-context sender/client nodes)
         self._completes = any(
             c.message_mode or c.header is not H.default_handler
             or c.packet is not H.default_handler
@@ -229,39 +236,34 @@ class Node:
         with obs.span("nic.step"):
             self.state, egress, to_host = self.nic.step(self.state, ingress)
 
-        # host datapath: deliver non-matching frames to the engines
+        # one blocking read of all this step gives the host: device_get
+        # starts every copy before it waits on any
+        fifo = ((self.state.counter_count, self.state.counters)
+                if self._completes else None)
         with obs.span("d2h.to_host"):
-            th_valid = np.asarray(to_host.valid)
-        if th_valid.any():
-            with obs.span("d2h.to_host"):
-                data = np.asarray(to_host.data)
-                lens = np.asarray(to_host.length)
-            host_frames = [data[i, :lens[i]].copy()
-                           for i in np.flatnonzero(th_valid)]
+            to_host, egress, fifo = jax.device_get((to_host, egress, fifo))
+
+        # host datapath: deliver non-matching frames to the engines
+        host_frames = _live_frames(to_host)
+        if host_frames:
             with obs.span("engine.frames"):
                 for e in self.engines:
                     e.on_host_frames(host_frames, now)
 
         # completion notifications
-        if self._completes:
-            comp, self.state = self.nic.pop_counters(self.state,
-                                                     slmp.COMPLETION_QUEUE)
-            if len(comp):
+        if fifo is not None:
+            q = slmp.COMPLETION_QUEUE
+            count, rings = fifo
+            if count[q]:
+                comp = spin_nic.drained(rings[q], int(count[q]))
+                self.state = self.nic.clear_counters(self.state, q)
                 self.completions.extend(int(c) for c in comp)
                 with obs.span("engine.completions"):
                     for e in self.engines:
                         e.on_completions(comp, now)
 
         # outbound = handler egress + engine-generated frames
-        out: List[np.ndarray] = []
-        with obs.span("d2h.egress"):
-            eg_valid = np.asarray(egress.valid)
-        if eg_valid.any():
-            with obs.span("d2h.egress"):
-                data = np.asarray(egress.data)
-                lens = np.asarray(egress.length)
-            out.extend(data[i, :lens[i]].copy()
-                       for i in np.flatnonzero(eg_valid))
+        out = _live_frames(egress)
         out.extend(self._poll(now))
         return out
 

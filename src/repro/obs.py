@@ -19,9 +19,12 @@ per node, never around a loop over nodes.
 ``nic.step``           dispatch of one node's NIC step
 ``nic.write``          dispatch of a small host write into ``NICState``
 ``d2h.ingress``        the host's wait for the delivered ingress batches
-``d2h.to_host``        read of a node's host-path (non-matching) frames
-``d2h.egress``         read of a node's handler egress
-``d2h.completions``    drain of a node's completion counter FIFO
+``d2h.to_host``        the one read of a node's NIC-step outputs: host-path
+                       (non-matching) frames, handler egress and the
+                       completion counter FIFO
+``d2h.egress``         retired from the program (the egress is read under
+                       ``d2h.to_host``); kept for trace readers that match it
+``d2h.completions``    ``SpinNIC.pop_counters``' read of a counter FIFO
 ``d2h.host_window``    an engine's read of the NIC's host DMA window
 ``d2h.link_stats``     read of the links' counters
 ``engine.poll``        a node's host engines polled: timers, retransmits,

@@ -1,10 +1,13 @@
 """Tests for repro.net: link model invariants, MAC routing, two-node
-SLMP reliability under loss, ping-pong, and fabric checkpointing."""
+SLMP reliability under loss, ping-pong, fabric checkpointing, and the
+node's one device read of each NIC step."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import apps, packet as pkt, slmp
+from repro import obs
+from repro.core import apps, handlers as H, packet as pkt, slmp
 from repro.net import (Fabric, Link, LinkConfig, Node, PingPongClient,
                        SlmpSenderEngine)
 
@@ -205,3 +208,132 @@ def test_slmp_sender_gives_up_after_max_retries():
         now += 1
         assert now < 1000
     assert sender.failed and not sender.done
+
+
+# ------------------------------------------- one device read a NIC step
+def _reference_tick(node, ingress, now):
+    """``Node.tick`` with each of the step's outputs read on its own:
+    valid, then data and length of each batch, and the completion FIFO
+    drained through ``SpinNIC.pop_counters``."""
+    def frames(batch):
+        valid = np.asarray(batch.valid)
+        if not valid.any():
+            return []
+        data, lens = np.asarray(batch.data), np.asarray(batch.length)
+        return [data[i, :lens[i]].copy() for i in np.flatnonzero(valid)]
+
+    node.state, egress, to_host = node.nic.step(node.state, ingress)
+    host_frames = frames(to_host)
+    if host_frames:
+        for e in node.engines:
+            e.on_host_frames(host_frames, now)
+    if node._completes:
+        comp, node.state = node.nic.pop_counters(node.state,
+                                                 slmp.COMPLETION_QUEUE)
+        if len(comp):
+            node.completions.extend(int(c) for c in comp)
+            for e in node.engines:
+                e.on_completions(comp, now)
+    out = frames(egress)
+    for e in node.engines:
+        out.extend(e.poll(now))
+    return out
+
+
+def _record_ticks(monkeypatch, tick):
+    """Runs ``tick`` as ``Node.tick`` and records what each call returns
+    and the names of the program spans it opens."""
+    calls, opened = [], []
+    span = obs.span
+
+    def counting(name):
+        opened.append(name)
+        return span(name)
+
+    def recording(node, ingress, now):
+        first = len(opened)
+        out = tick(node, ingress, now)
+        calls.append((node.name, now, [f.tobytes() for f in out],
+                      opened[first:]))
+        return out
+    monkeypatch.setattr(obs, "span", counting)
+    monkeypatch.setattr(Node, "tick", recording)
+    return calls
+
+
+def _lossy_transfers(monkeypatch, tick):
+    """Three SLMP messages over a lossy, duplicating, reordering wire: the
+    receiver's tail handler pushes a completion on every EOM arrival and
+    its ACKs reach the sender's host path."""
+    calls = _record_ticks(monkeypatch, tick)
+    rng = np.random.default_rng(0)
+    cfg = slmp.SlmpSenderConfig(window=4, mtu_payload=512, timeout=6,
+                                src_mac=pkt.node_mac(0),
+                                dst_mac=pkt.node_mac(1))
+    senders = [SlmpSenderEngine(rng.integers(0, 256, 3000).astype(np.uint8),
+                                msg_id=40 + i, cfg=cfg) for i in range(3)]
+    a = Node("sender", pkt.node_mac(0), [apps.make_null_context()],
+             engines=senders, batch=8)
+    b = Node("recv", pkt.node_mac(1), [slmp.make_slmp_context()], batch=8,
+             host_bytes=1 << 14)
+    fab = Fabric([a, b], link_cfg=LinkConfig(loss=0.15, latency=2, jitter=2,
+                                             duplicate=0.2), seed=3)
+    fab.run(max_ticks=3000)
+    assert all(s.done and not s.failed for s in senders)
+    return fab, calls
+
+
+def _host_tree(tree):
+    return [np.asarray(x) for x in jax.tree.leaves(tree)]
+
+
+def test_node_tick_reads_a_step_as_separate_reads_would(monkeypatch):
+    with monkeypatch.context() as m:
+        fused, fused_calls = _lossy_transfers(m, Node.tick)
+    with monkeypatch.context() as m:
+        ref, ref_calls = _lossy_transfers(m, _reference_tick)
+    frames = [c[:3] for c in fused_calls]
+    assert frames == [c[:3] for c in ref_calls]
+    assert sum(len(c[2]) for c in fused_calls) > 0        # egress left
+    recv, ref_recv = fused.node("recv"), ref.node("recv")
+    assert len(recv.completions) > 3                      # duplicates too
+    assert recv.completions == ref_recv.completions
+    for n, r in zip(fused.nodes, ref.nodes):
+        for x, y in zip(_host_tree(n.state), _host_tree(r.state)):
+            np.testing.assert_array_equal(x, y)
+
+
+def test_busy_node_tick_opens_one_read_span(monkeypatch):
+    _, calls = _lossy_transfers(monkeypatch, Node.tick)
+    reads = [[n for n in c[3] if n.startswith("d2h.")] for c in calls]
+    assert calls and all(r == ["d2h.to_host"] for r in reads)
+    # some of these ticks drained completions
+    assert any("engine.completions" in c[3] for c in calls)
+
+
+def test_node_tick_drains_an_overrun_fifo_as_pop_counters():
+    """More than a FIFO's length of completions between two drains: only
+    the newest ``COUNTER_QUEUE_LEN`` survive, oldest first."""
+    node = Node("hostmode", pkt.node_mac(1), [apps.make_icmp_host_context()],
+                batch=32)
+    pushed = 0
+    for step in range(3):
+        # ICMP echoes of distinct lengths: each push carries its frame's
+        # length, so the drained order is visible
+        frames = [pkt.make_icmp_echo(np.zeros(8 + 32 * step + i, np.uint8),
+                                     seq=i) for i in range(32)]
+        node.state, _, _ = node.nic.step(node.state,
+                                         pkt.stack_frames(frames, n=32))
+        pushed += 32
+    assert pushed > H.COUNTER_QUEUE_LEN
+    before = jax.tree.map(jnp.copy, node.state)
+    want, _ = node.nic.pop_counters(before, slmp.COMPLETION_QUEUE)
+    assert len(want) == H.COUNTER_QUEUE_LEN
+    assert len(set(want.tolist())) == H.COUNTER_QUEUE_LEN
+
+    empty = pkt.stack_frames([], n=32)
+    node.tick(empty, now=0)
+    assert node.completions == want.tolist()
+    assert int(node.state.counter_count[slmp.COMPLETION_QUEUE]) == 0
+    node.tick(empty, now=1)                    # a drain is not a peek
+    assert node.completions == want.tolist()

@@ -34,7 +34,9 @@ def test_every_span_in_the_program_is_catalogued_and_placed():
     used = set()
     for path in (ROOT / "src" / "repro").rglob("*.py"):
         used |= set(re.findall(r'obs\.span\("([^"]+)"\)', path.read_text()))
-    assert used == set(obs.SPANS)
+    # ``d2h.egress`` is read under ``d2h.to_host`` now; it stays in the
+    # catalogue only because the trace readers (bench/spans.py) match it
+    assert used == set(obs.SPANS) - {"d2h.egress"}
     with pytest.raises(ValueError, match="catalogue"):
         obs.span("nic.stepp")
 
@@ -48,9 +50,11 @@ def _within(ev, outer) -> int:
 # A 2-rank typed receive (rendezvous into a committed datatype), and a
 # 4-rank 128 KiB allreduce: segmented Rabenseifner over the credit-managed
 # rendezvous, so engines read the DMA window, arm the expect table and
-# step a collective plan.
-CASES = {"ddt_fig10_2r.complex_loss5": {"mpi.plan"},
-         "allreduce_8r.1MiB_loss2": set()}
+# step a collective plan.  No traced tick reads egress or the completion
+# FIFO on its own: a NIC step's outputs come back in one ``d2h.to_host``.
+CASES = {"ddt_fig10_2r.complex_loss5": {"mpi.plan", "d2h.egress",
+                                        "d2h.completions"},
+         "allreduce_8r.1MiB_loss2": {"d2h.egress", "d2h.completions"}}
 
 
 @pytest.mark.parametrize("workload", list(CASES))
