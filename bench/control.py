@@ -6,7 +6,7 @@ For each seed this builds the cell as ``run.py`` does, runs its closed loop
 for ``--seconds`` (the harness's window, untimed here), and compares every
 completed operation twice with the reference: the program's outputs, and
 the control's, which is the reference computed one precision step lower
-(``reference.control_outputs``) put in the program's place.  It prints one
+(the operation's ``check.control``) put in the program's place.  It prints one
 JSON line per seed with the worst reading of each number for both, and a
 last line with, per number, the largest program reading and the smallest
 control reading: the two readings a limit is set between.  One process
@@ -23,11 +23,11 @@ for _p in (str(ROOT / "src"), str(ROOT)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import generator, reference, run, spec  # noqa: E402
+from bench import generator, run, spec  # noqa: E402
 
 
 def readings(cell, seed: int, seconds: float) -> dict:
-    traffic = generator.build(cell.config, cell.mix, seed)
+    traffic = generator.build(cell, seed)
     traffic.warm_up()
     _, tick_s, _, done = run.measure(traffic, seconds)
     program, control = {}, {}
@@ -35,10 +35,10 @@ def readings(cell, seed: int, seconds: float) -> dict:
         if d.error:
             raise RuntimeError(f"seed {seed}: operation {d.index} failed: "
                                f"{d.error}")
-        low = reference.control_outputs(cell.mix, cell.config, d.inputs)
+        low = cell.check.control(cell.mix, cell.config, d.inputs)
         for out, worst in ((d.outputs, program), (low, control)):
-            for name, v in reference.compare(cell.mix, cell.config,
-                                             d.inputs, out).items():
+            for name, v in cell.check.compare(cell.mix, cell.config,
+                                              d.inputs, out).items():
                 worst[name] = max(worst.get(name, 0.0), v)
     return dict(seed=seed, ops=len(done), ticks=len(tick_s),
                 program=program, control=control)

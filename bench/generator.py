@@ -5,16 +5,13 @@ A configuration (``bench/configs/<name>.json``) gives the ranks, the
 messaging settings (``repro.mpi.MpiConfig`` fields), the link settings
 (``repro.net.LinkConfig`` fields but the loss) and the datatypes committed
 on the NICs.  A traffic mix (``bench/traffic/<name>.json``) gives the
-operation and its sizes, the loss rate on every link and the seed of the
-links' loss process (``loss_seed``), the largest burst of frames one node may
-be handed in a tick (``warm_frames``), and the limits of the numbers
-compared.  Two operations exist:
-
-* ``allreduce``: ``mpi.iallreduce`` of one ``dtype`` vector of
-  ``bytes_per_rank`` per rank, standard normal values;
-* ``typed_recv``: ``irecv`` of raw bytes on rank ``dst`` and ``isend`` from
-  rank ``src`` with the committed ``datatype``, whose memory is standard
-  normal float32 values.
+operation (``op``) and its sizes, the loss rate on every link and the seed of
+the links' loss process (``loss_seed``), the largest burst of frames one node
+may be handed in a tick (``warm_frames``), and the limits of the numbers
+compared.  The operation is the cell's two modules (``bench/spec.py``): its
+reference draws the inputs (``check.draw``), its program side posts the
+requests and collects the outputs (``op.post``, ``op.outputs``); what every
+operation shares, the counters and the modelled statistics, is here.
 
 Every input comes from the seed: operation ``i`` of a run draws from
 ``default_rng([seed, 2, i])`` and the warm-up operation from
@@ -60,7 +57,10 @@ def to_ddt(spec) -> ddtlib.DDT:
     raise ValueError(f"unknown datatype constructor {kind!r}")
 
 
-def build(config: dict, mix: dict, seed: int) -> "Traffic":
+def build(cell, seed: int) -> "Traffic":
+    """The communicator of ``cell`` (a ``spec.Cell``) and its traffic from
+    ``seed``."""
+    config, mix = cell.config, cell.mix
     reg = mpi.DatatypeRegistry()
     ids = {d["name"]: reg.register(to_ddt(d["type"]), count=d["count"],
                                    name=d["name"])
@@ -69,7 +69,7 @@ def build(config: dict, mix: dict, seed: int) -> "Traffic":
     comm = mpi.Communicator(config["ranks"], registry=reg, link_cfg=link,
                             seed=mix["loss_seed"],
                             cfg=mpi.MpiConfig(**config["mpi"]))
-    return Traffic(comm, mix, seed, ids)
+    return Traffic(comm, cell, seed, ids)
 
 
 @dataclasses.dataclass
@@ -93,26 +93,10 @@ class Op:
         self.tick0 = comm.now
         self.eng0 = _engine_totals(comm)
         self.link0 = _link_totals(comm)
-        mix = traffic.mix
-        if mix["op"] == "allreduce":
-            n = mix["bytes_per_rank"] // np.dtype(mix["dtype"]).itemsize
-            self.inputs = [rng.standard_normal(n).astype(mix["dtype"])
-                           for _ in range(comm.n_ranks)]
-            self.reqs = [mpi.iallreduce(comm, [v.copy() for v in self.inputs],
-                                        algorithm=mix["algorithm"])]
-        elif mix["op"] == "typed_recv":
-            cid = traffic.ids[mix["datatype"]]
-            span = comm.registry.mem_bytes(cid)
-            self.inputs = rng.standard_normal(span // 4).astype(
-                np.float32).view(np.uint8)
-            self.buf = np.zeros(span, np.uint8)
-            self.reqs = [
-                comm.irecv(mix["dst"], self.buf, source=mix["src"],
-                           tag=mix["tag"]),
-                comm.isend(mix["src"], mix["dst"], self.inputs.copy(),
-                           tag=mix["tag"], datatype=cid)]
-        else:
-            raise ValueError(f"unknown operation {mix['op']!r}")
+        cell = traffic.cell
+        self.inputs = cell.check.draw(cell.mix, cell.config, rng)
+        self.posted = cell.op.post(comm, cell.mix, traffic.ids, self.inputs)
+        self.reqs = self.posted[0]
 
     def error(self) -> Optional[str]:
         errs = [r.error for r in self.reqs if r.error]
@@ -124,10 +108,7 @@ class Op:
 
     def complete(self) -> Done:
         comm = self.t.comm
-        if self.t.mix["op"] == "allreduce":
-            outputs = [np.array(o) for o in self.reqs[0].result or []]
-        else:
-            outputs = self.buf.copy()
+        outputs = self.t.cell.op.outputs(self.posted)
         eng, link = _engine_totals(comm), _link_totals(comm)
         modelled = dict(
             ticks=comm.now - self.tick0,
@@ -140,9 +121,9 @@ class Op:
 
 
 class Traffic:
-    def __init__(self, comm, mix: dict, seed: int, ids: dict):
+    def __init__(self, comm, cell, seed: int, ids: dict):
         self.comm = comm
-        self.mix = mix
+        self.cell = cell
         self.seed = seed
         self.ids = ids
 
@@ -175,7 +156,7 @@ class Traffic:
                  for r in range(n)]
         fab = Fabric(nodes, link_cfg=comm.link_cfg)
         size = 1
-        while size <= self.mix["warm_frames"]:
+        while size <= self.cell.mix["warm_frames"]:
             burst.count = size
             fab.tick()
             size *= 2

@@ -16,7 +16,8 @@ A cell of ``BENCHMARK.json`` is one configuration under one traffic mix
    once.  When ``--seconds`` have passed it waits for every live device array;
    the operation still in flight is not counted, its ticks are;
 4. the check: every operation completed in the window is compared with the
-   plain reference (``bench/reference.py``) against the mix's limits.
+   plain reference of the mix's operation (``bench/checks/<op>.py``)
+   against the mix's limits.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result carries the
@@ -47,7 +48,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import jax  # noqa: E402
 
-from bench import generator, reference, spec  # noqa: E402
+from bench import generator, spec  # noqa: E402
 from bench import trace as tracelib  # noqa: E402
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -140,7 +141,7 @@ def check(cell, done):
     for d in done:
         bad = d.error is not None
         if not bad:
-            for name, value in reference.compare(
+            for name, value in cell.check.compare(
                     cell.mix, cell.config, d.inputs, d.outputs).items():
                 # JSON has no infinity: a result that cannot be compared
                 # reads as the largest float
@@ -181,7 +182,7 @@ def _run(cell, devs, meter, args, root: Path) -> dict:
     use_compile_cache(root)
 
     with jax.profiler.TraceAnnotation("setup"):
-        traffic = generator.build(cell.config, cell.mix, args.seed)
+        traffic = generator.build(cell, args.seed)
         warm_ticks = traffic.warm_up()
     setup_compile_s, setup_compiles = meter.seconds, meter.count
     print(json.dumps(dict(

@@ -15,10 +15,10 @@ ROOT = Path(__file__).resolve().parents[2]
 
 @pytest.fixture
 def bench_root(tmp_path) -> Path:
-    """``BENCHMARK.json`` and the configuration, traffic and metric files,
-    copied into a temporary checkout that a test may edit."""
+    """``BENCHMARK.json`` and the configuration, traffic, operation and
+    metric files, copied into a temporary checkout that a test may edit."""
     shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "ops", "checks", "metrics"):
         shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
     return tmp_path
 

@@ -6,11 +6,12 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference
+from bench import reference, spec
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -23,14 +24,8 @@ def _mix(w):
         (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
 
 
-def _inputs(mix, config, rng):
-    if mix["op"] == "allreduce":
-        n = mix["bytes_per_rank"] // 4
-        return [rng.standard_normal(n).astype(np.float32)
-                for _ in range(config["ranks"])]
-    dt = next(d for d in config["datatypes"] if d["name"] == mix["datatype"])
-    _, span = reference.typed_layout(dt["type"], dt["count"])
-    return rng.standard_normal(span // 4).astype(np.float32).view(np.uint8)
+def _check(mix):
+    return spec.load_check(ROOT, mix["op"])
 
 
 def test_fig9_complex_typemap():
@@ -50,22 +45,15 @@ def test_fig9_complex_typemap():
 def test_control_fails_and_reference_passes(workload):
     mix = _mix(workload)
     config = CONFIGS[workload["config"]]
+    check = _check(mix)
     for seed in range(3):
-        inputs = _inputs(mix, config, np.random.default_rng(seed))
-        low = reference.control_outputs(mix, config, inputs)
-        for name, value in reference.compare(mix, config, inputs,
-                                             low).items():
+        inputs = check.draw(mix, config, np.random.default_rng(seed))
+        low = check.control(mix, config, inputs)
+        for name, value in check.compare(mix, config, inputs, low).items():
             assert value > mix["limits"][name], (name, value)
-        if mix["op"] == "allreduce":
-            exact = [np.sum(np.stack(inputs).astype(np.float64), axis=0)
-                     .astype(np.float32)] * len(inputs)
-        else:
-            dt = next(d for d in config["datatypes"]
-                      if d["name"] == mix["datatype"])
-            exact = reference.expected_typed_recv(dt["type"], dt["count"],
-                                                  inputs)
-        for name, value in reference.compare(mix, config, inputs,
-                                             exact).items():
+        exact = check.expected(mix, config, inputs)
+        for name, value in check.compare(mix, config, inputs,
+                                         exact).items():
             assert value <= mix["limits"][name], (name, value)
 
 
@@ -105,8 +93,20 @@ def _fault(monkeypatch, kind: str) -> None:
 
 FAULTS = ("state_unchanged", "half_left_out", "exchange_left_out",
           "answer_altered")
+
+
+def _largest_input(workload) -> int:
+    """Bytes of the largest array that one operation of the cell draws."""
+    mix = _mix(workload)
+    inputs = _check(mix).draw(mix, CONFIGS[workload["config"]],
+                              np.random.default_rng(0))
+    return max(a.nbytes for a in jax.tree_util.tree_leaves(inputs))
+
+
+# the cells light enough for the CPU to run them whole: no input array of
+# an operation reaches 64 KiB
 SMALL_CELLS = [w for w in BENCH["workloads"]
-               if _mix(w).get("bytes_per_rank", 0) < 64 << 10]
+               if _largest_input(w) < 64 << 10]
 
 
 @pytest.mark.parametrize("workload", SMALL_CELLS, ids=lambda w: w["name"])

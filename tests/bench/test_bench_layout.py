@@ -25,7 +25,12 @@ def test_every_cell_resolves_to_its_files(workload):
     w = next(w for w in BENCH["workloads"] if w["name"] == workload)
     assert cell.config["name"] == w["config"]
     assert cell.chips == 1
-    assert cell.mix["op"] in ("allreduce", "typed_recv")
+    op = cell.mix["op"]
+    assert cell.op.__file__ == str(ROOT / "bench" / "ops" / f"{op}.py")
+    assert cell.check.__file__ == str(ROOT / "bench" / "checks" / f"{op}.py")
+    assert all(callable(getattr(cell.op, f)) for f in ("post", "outputs"))
+    assert all(callable(getattr(cell.check, f)) for f in (
+        "draw", "expected", "compare", "control", "small"))
     assert set(cell.mix["limits"])
     assert {m.name for m in cell.end_to_end} == {
         m["name"] for m in BENCH["end_to_end"]}
@@ -58,10 +63,13 @@ def test_added_config_traffic_and_metric_are_found(bench_root, run_cpu):
         json.dumps(dict(
             name="tiny_2r", ranks=2, mpi={"batch": 8}, link={"latency": 1},
             datatypes=[], reduced={})))
+    # an existing mix at another size and loss rate
+    mix = json.loads(
+        (bench_root / "bench" / "traffic" / "2KiB_loss2.json").read_text())
+    mix.update(bytes_per_rank=4096, loss=0.0, warm_frames=8,
+               limits={"sum_gap": 1e-5})
     (bench_root / "bench" / "traffic" / "4KiB_loss0.json").write_text(
-        json.dumps(dict(op="allreduce", dtype="float32",
-                        bytes_per_rank=4096, algorithm="auto", loss=0.0,
-                        loss_seed=1, warm_frames=8, limits={"sum_gap": 1e-5})))
+        json.dumps(mix))
     (bench_root / "bench" / "metrics" / "ticks_in_window.py").write_text(
         "def read(run):\n    return float(run.ticks)\n")
     bench = json.loads((bench_root / "BENCHMARK.json").read_text())
@@ -75,6 +83,9 @@ def test_added_config_traffic_and_metric_are_found(bench_root, run_cpu):
         name="ticks_in_window", unit="ticks", better="higher",
         source="host_clock", layer="fabric tick", moves="ticks_per_s",
         workloads=["tiny_2r.4KiB_loss0"]))
+    # an existing metric that lists its cells is given the new one
+    next(m for m in bench["per_layer"] if m["name"] == "compile_s")[
+        "workloads"].append("tiny_2r.4KiB_loss0")
     (bench_root / "BENCHMARK.json").write_text(json.dumps(bench))
 
     cell = spec.load(bench_root, "tiny_2r.4KiB_loss0")

@@ -7,22 +7,23 @@ from pathlib import Path
 
 import pytest
 
+from bench import spec
+
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
 def _shrink(root: Path, workload: dict) -> None:
-    """At most 4 ranks and 128 KiB per rank: small enough for the CPU, and
-    above the size at which an allreduce goes segmented Rabenseifner."""
-    cfg_file = next(root / c["file"] for c in BENCH["configs"]
+    """Writes the cell's configuration and mix in ``root`` over with the
+    CPU-test size that its operation's ``small`` gives."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    cfg_file = next(root / c["file"] for c in bench["configs"]
                     if c["name"] == workload["config"])
-    cfg = json.loads(cfg_file.read_text())
-    cfg["ranks"] = min(cfg["ranks"], 4)
-    cfg_file.write_text(json.dumps(cfg))
     mix_file = root / "bench" / "traffic" / f"{workload['traffic']}.json"
+    cfg = json.loads(cfg_file.read_text())
     mix = json.loads(mix_file.read_text())
-    if "bytes_per_rank" in mix:
-        mix["bytes_per_rank"] = min(mix["bytes_per_rank"], 128 << 10)
+    mix, cfg = spec.load_check(root, mix["op"]).small(mix, cfg)
+    cfg_file.write_text(json.dumps(cfg))
     mix_file.write_text(json.dumps(mix))
 
 
@@ -53,7 +54,7 @@ def test_modelled_statistics_do_not_depend_on_the_seed(bench_root, run_cpu):
     cell = "ddt_fig10_2r.complex_loss5"
     per_op = []
     for seed in (5, 2**33 + 1):
-        run_cpu(bench_root, cell, seed=seed, seconds=1)
+        run_cpu(bench_root, cell, seed=seed, seconds=3)
         out = json.loads((bench_root / ".bench_out"
                           / f"{cell}.seed{seed}.trace0.json").read_text())
         per_op.append(out["modelled"])
