@@ -1,9 +1,13 @@
 """The program's own host spans in a traced window, and what they cover.
 
 The program wraps each host site of the fabric tick in a named
-``jax.profiler.TraceAnnotation`` (``<layer>.<site>``).  The names are
-written out here rather than imported from the program, so that a rename
-there leaves these readers reading nothing instead of something else.
+``jax.profiler.TraceAnnotation`` (``<layer>.<site>``).  A host event is a
+program span when its name has that form and its layer is one of the
+program's (``SPAN``), so a site the program adds to a known layer is read
+without an edit here.  The layers are written out here rather than
+imported from the program, so that a renamed layer leaves these readers
+reading nothing instead of something else.  The harness's own spans
+(``window``, ``tick``, ...) have no layer.
 
 A span's self time is its duration less the part of it that program spans
 nested inside it cover; runtime annotations (``PjitFunction``,
@@ -13,10 +17,15 @@ span's parent and every stretch's innermost span.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Tuple
+import dataclasses
+import re
+from typing import Dict, List, Tuple
 
 from bench.trace import Event
 
+SPAN = re.compile(r"^(link|nic|d2h|engine|mpi|fabric)\.[a-z_]+$")
+# the catalogue's sites today, which the program's own test holds its
+# catalogue to; the readers match by ``SPAN`` and the layers below
 PROGRAM_SPANS = frozenset((
     "link.pop", "link.push", "nic.step", "nic.write",
     "d2h.ingress", "d2h.to_host", "d2h.egress", "d2h.completions",
@@ -25,9 +34,21 @@ PROGRAM_SPANS = frozenset((
     "mpi.plan",
     "fabric.route", "fabric.pack",
 ))
-D2H = frozenset(n for n in PROGRAM_SPANS if n.startswith("d2h."))
+
+
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    """Every program span of one layer: ``name in Layer("d2h")`` holds for
+    each ``d2h.<site>``, those the program adds later included."""
+    layer: str
+
+    def __contains__(self, name: str) -> bool:
+        return name.startswith(self.layer + ".")
+
+
+D2H = Layer("d2h")
+ENGINE = Layer("engine")
 DISPATCH = frozenset(("link.pop", "link.push", "nic.step", "nic.write"))
-ENGINE = frozenset(n for n in PROGRAM_SPANS if n.startswith("engine."))
 PLAN = frozenset(("mpi.plan",))
 FABRIC = frozenset(("fabric.route", "fabric.pack"))
 NONE = "none"
@@ -38,7 +59,7 @@ def program_spans(trace) -> List[Event]:
     clipped to the window, in order of start (an enclosing span first)."""
     lo, hi = trace.window.start_ns, trace.window.end_ns
     spans = [Event(ev.name, max(ev.start_ns, lo), min(ev.end_ns, hi))
-             for ev in trace.host if ev.name in PROGRAM_SPANS]
+             for ev in trace.host if SPAN.match(ev.name)]
     spans.sort(key=lambda ev: (ev.start_ns, -ev.end_ns))
     return spans
 
@@ -54,17 +75,17 @@ def _parents(spans: List[Event]) -> List[int]:
     return out
 
 
-def self_seconds(trace, names: Iterable[str]) -> Dict[str, float]:
-    """Self seconds of the spans of each name in ``names``, summed."""
+def self_seconds(trace) -> Dict[str, float]:
+    """Self seconds of the program spans of each name in the window,
+    summed."""
     spans = program_spans(trace)
     own = [ev.end_ns - ev.start_ns for ev in spans]
     for i, p in enumerate(_parents(spans)):
         if p >= 0:
             own[p] -= spans[i].end_ns - spans[i].start_ns
-    out = {name: 0.0 for name in names}
+    out: Dict[str, float] = {}
     for ev, ns in zip(spans, own):
-        if ev.name in out:
-            out[ev.name] += ns / 1e9
+        out[ev.name] = out.get(ev.name, 0.0) + ns / 1e9
     return out
 
 
@@ -115,8 +136,10 @@ def idle_by_span(trace) -> Dict[str, float]:
 
 
 def per_tick_ms(run, names) -> "float | None":
-    """Self milliseconds of the spans in ``names`` per fabric tick; None
-    where the window holds no program span at all."""
+    """Self milliseconds of the spans whose names are ``in names`` (a set
+    of names or a ``Layer``) per fabric tick; None where the window holds
+    no program span at all."""
     if run.trace is None or not run.ticks or not program_spans(run.trace):
         return None
-    return 1e3 * sum(self_seconds(run.trace, names).values()) / run.ticks
+    return 1e3 * sum(s for name, s in self_seconds(run.trace).items()
+                     if name in names) / run.ticks

@@ -13,14 +13,20 @@ from jax.experimental.compilation_cache import compilation_cache
 ROOT = Path(__file__).resolve().parents[2]
 
 
+def copy_definition(dst: Path) -> Path:
+    """Copies ``BENCHMARK.json`` and the configuration, traffic, operation
+    and metric files into the checkout ``dst``."""
+    shutil.copy(ROOT / "BENCHMARK.json", dst)
+    for d in ("configs", "traffic", "ops", "checks", "metrics"):
+        shutil.copytree(ROOT / "bench" / d, dst / "bench" / d)
+    return dst
+
+
 @pytest.fixture
 def bench_root(tmp_path) -> Path:
-    """``BENCHMARK.json`` and the configuration, traffic, operation and
-    metric files, copied into a temporary checkout that a test may edit."""
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-    for d in ("configs", "traffic", "ops", "checks", "metrics"):
-        shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
-    return tmp_path
+    """The benchmark's definition files in a temporary checkout that a test
+    may edit."""
+    return copy_definition(tmp_path)
 
 
 @pytest.fixture

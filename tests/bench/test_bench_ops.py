@@ -1,26 +1,22 @@
 """An operation is two files found by the mix's ``op``: one added as files
 alone is posted, collected and compared; the references import nothing of
-the simulator; and the operations of the cells draw, model and compare
-exactly what the harness recorded for them before operations were files."""
+the simulator; and the operations of each cell draw, model and compare
+exactly what its record (``tests/bench/data/fingerprints/<cell>.json``)
+holds."""
 from __future__ import annotations
 
-import hashlib
 import json
 import subprocess
 import sys
 from pathlib import Path
 
-import numpy as np
 import pytest
 
-from bench import generator, spec
-from tests.bench.test_bench_traffic import _shrink
+from bench import spec
+from tests.bench import fingerprint as fp
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-RECORDED = json.loads(
-    (Path(__file__).resolve().parent / "data" / "ops_fingerprint.json")
-    .read_text())
 
 # a contiguous byte message from rank src to rank dst
 BYTE_SEND_OP = '''
@@ -121,34 +117,23 @@ def test_references_import_nothing_of_the_simulator():
     assert loaded.strip() == "[]"
 
 
-def _digest(x) -> str:
-    h = hashlib.sha256()
-    for a in (x if isinstance(x, list) else [x]):
-        a = np.ascontiguousarray(a)
-        h.update(f"{a.dtype}{a.shape}".encode())
-        h.update(a.tobytes())
-    return h.hexdigest()
+def _record(data: Path, workload: str) -> dict:
+    """The recorded fingerprints of ``workload``, per seed; a cell with no
+    record fails, naming the file and the command that writes it."""
+    path = data / f"{workload}.json"
+    if not path.is_file():
+        pytest.fail(f"no fingerprint record at {path}: write it with "
+                    f"`{fp.command(workload)}`", pytrace=False)
+    return json.loads(path.read_text())["seeds"]
 
 
-def fingerprint(cell, seed: int, n_ops: int = 2) -> dict:
-    """Warm-up ticks, and of the first operations of a run: digests of
-    their inputs and outputs, their modelled statistics and the numbers
-    compared, as the harness's closed loop makes them."""
-    traffic = generator.build(cell, seed)
-    out = dict(warmup_ticks=traffic.warm_up(), ops=[])
-    for index in range(n_ops):
-        op = traffic.post(index)
-        for _ in range(1_000_000):
-            traffic.comm.progress(1)
-            if op.finished():
-                break
-        d = op.complete()
-        out["ops"].append(dict(
-            inputs=_digest(d.inputs), outputs=_digest(d.outputs),
-            error=d.error, modelled=d.modelled,
-            compared=cell.check.compare(cell.mix, cell.config, d.inputs,
-                                        d.outputs)))
-    return out
+def test_missing_record_names_its_path_and_command(tmp_path):
+    with pytest.raises(pytest.fail.Exception) as failed:
+        _record(tmp_path, "new_4r.64KiB_loss1")
+    msg = str(failed.value)
+    assert str(tmp_path / "new_4r.64KiB_loss1.json") in msg
+    assert ("python3 tests/bench/fingerprint.py --workload "
+            "new_4r.64KiB_loss1") in msg
 
 
 @pytest.mark.parametrize("workload", BENCH["workloads"],
@@ -156,8 +141,6 @@ def fingerprint(cell, seed: int, n_ops: int = 2) -> dict:
 def test_cell_repeats_what_was_recorded(bench_root, workload):
     """Seeds 1 and 2 at the ``small`` size draw byte-identical inputs and
     give identical outputs, modelled statistics and numbers compared to
-    those recorded with the harness that branched on the operation."""
-    _shrink(bench_root, workload)
-    cell = spec.load(bench_root, workload["name"])
-    got = {str(seed): fingerprint(cell, seed) for seed in (1, 2)}
-    assert got == RECORDED["cells"][workload["name"]]
+    those of the cell's record."""
+    want = _record(fp.DATA, workload["name"])
+    assert fp.fingerprints(bench_root, workload) == want

@@ -2,9 +2,8 @@
 ``*_per_tick`` metrics), on a trace of the ``ddt_fig10_2r.complex_loss5``
 cell recorded on one TPU v5e by a build with the spans in place (a 1 s
 traced window; see the fixture's ``recorded`` key), and on the earlier
-recording by a build without them.  The readers are loaded straight from
-their ``BENCHMARK.json`` entries: the cells that list them are the two
-``allreduce_8r`` ones, and this fixture is of the DDT cell."""
+recording by a build without them, and on hand-made events.  The readers
+are loaded straight from their ``BENCHMARK.json`` entries."""
 from __future__ import annotations
 
 import json
@@ -16,7 +15,7 @@ import pytest
 from bench import spans
 from bench import spec
 from bench.run import Run
-from bench.trace import Trace
+from bench.trace import Event, Trace
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
@@ -68,14 +67,16 @@ def test_self_seconds_agrees_with_a_plain_sweep(recorded):
     run, _ = recorded
     evs = spans.program_spans(run.trace)
     assert len(evs) > 100
-    want = dict.fromkeys(spans.PROGRAM_SPANS, 0.0)
+    want = {}
     for i, a in enumerate(evs):
         # in order of start, an enclosing span first
         inner = [(b.start_ns, b.end_ns) for b in evs[i + 1:]
                  if b.start_ns < a.end_ns and b.end_ns <= a.end_ns]
-        want[a.name] += (a.end_ns - a.start_ns - _union(inner)) / 1e9
-    got = spans.self_seconds(run.trace, spans.PROGRAM_SPANS)
+        want[a.name] = want.get(a.name, 0.0) + (
+            a.end_ns - a.start_ns - _union(inner)) / 1e9
+    got = spans.self_seconds(run.trace)
     assert got == pytest.approx(want, rel=1e-9, abs=1e-12)
+    assert set(got) <= spans.PROGRAM_SPANS
 
 
 def test_idle_by_span_sums_to_the_idle_time(recorded):
@@ -113,3 +114,31 @@ def test_every_read_of_a_recorded_tick_is_named(recorded):
     assert len(reads) > 5 * len(ticks)
     assert all(inside(ev, d2h) == 1 for ev in reads)
     assert all(inside(ev, evs) for ev in in_ticks)
+
+
+def test_a_new_span_of_a_known_layer_is_read():
+    """A site the program adds, ``d2h.new_read``, is a program span by its
+    layer alone: counted and timed as a read, and taken out of its
+    parent's self time; the harness's ``tick`` and the runtime's
+    ``np.asarray(jax.Array)`` are not program spans."""
+    assert "d2h.new_read" not in spans.PROGRAM_SPANS
+    host = [Event("tick", 0, 1000), Event("nic.step", 100, 600),
+            Event("d2h.new_read", 200, 450),
+            Event("np.asarray(jax.Array)", 210, 440),
+            Event("PjitFunction(_step_impl)", 110, 190),
+            Event("engine.poll", 700, 900), Event("window", 0, 1000)]
+    trace = Trace(Event("window", 0, 1000), [[]], [[]], host)
+    run = Run(tick_s=[1e-6], window_s=1e-6, setup_s=0.0,
+              setup_compile_s=0.0, trace=trace)
+    assert [ev.name for ev in spans.program_spans(trace)] == [
+        "nic.step", "d2h.new_read", "engine.poll"]
+    assert spans.self_seconds(trace) == pytest.approx({
+        "nic.step": 250e-9, "d2h.new_read": 250e-9, "engine.poll": 200e-9})
+    got = {name: m.read(run) for name, m in _readers().items()}
+    assert got == pytest.approx({
+        "d2h_syncs_per_tick": 1.0, "d2h_ms_per_tick": 250e-6,
+        "dispatch_ms_per_tick": 250e-6, "engine_ms_per_tick": 200e-6,
+        "plan_ms_per_tick": 0.0, "fabric_host_ms_per_tick": 0.0})
+    assert spans.idle_by_span(trace) == pytest.approx({
+        "none": 300e-9, "nic.step": 250e-9, "d2h.new_read": 250e-9,
+        "engine.poll": 200e-9})
