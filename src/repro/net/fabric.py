@@ -14,9 +14,14 @@ is batched across nodes: one vmapped ``pop`` drains all N links in a
 single device call, destination MACs of all egress frames are matched
 against the node-MAC matrix in one vectorized compare (no per-frame
 ``bytes()``/dict hops), and all routed traffic lands on the links through
-one vmapped ``push``.  Nodes whose link delivered nothing this tick skip
-the NIC step entirely (``Node.tick_idle``) — on a mostly-idle fabric the
-tick cost is one pop, N cheap engine polls, and at most one push.
+one vmapped ``push``.  Every node whose link delivered frames has its
+NIC step launched first (``Node.launch``), in node order; one
+``device_get`` then reads the outputs of all of them (``fetch``), so one
+read serves every busy node, and only then does each node, in order,
+hand its frames and completions to its engines (``Node.tick``).  Nodes
+whose link delivered nothing this tick skip the NIC step entirely
+(``Node.tick_idle``) — on a mostly-idle fabric the tick cost is one pop,
+N cheap engine polls, and at most one push.
 Heterogeneous ``link_cfgs`` / batch sizes fall back to the per-link loop.
 
 The whole system state (per-node ``NICState``, per-link ``LinkState``,
@@ -36,7 +41,7 @@ import numpy as np
 from repro import obs
 from repro.core import packet as pkt
 from repro.net import link as linklib
-from repro.net.node import Node
+from repro.net.node import Node, fetch
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -124,11 +129,19 @@ class Fabric:
         if busy.any():
             with obs.span("d2h.ingress"):
                 data, length = np.asarray(ing.data), np.asarray(ing.length)
+        # launch every busy node's NIC step, then read all their outputs
+        # at once: the device runs the queued steps while the host
+        # dispatches the next, and the one wait covers what is left
+        ingress = {i: pkt.PacketBatch(data[i], length[i], valid[i])
+                   for i in np.flatnonzero(busy).tolist()}
+        for i, batch in ingress.items():
+            self.nodes[i].launch(batch)
+        if ingress:
+            fetch([self.nodes[i] for i in ingress])
         outbound: List[List[np.ndarray]] = [[] for _ in self.nodes]
         for i, node in enumerate(self.nodes):
-            if busy[i]:
-                frames = node.tick(pkt.PacketBatch(
-                    data[i], length[i], valid[i]), now)
+            if i in ingress:
+                frames = node.tick(ingress[i], now)
             else:
                 frames = node.tick_idle(now)
             self._route(frames, outbound)

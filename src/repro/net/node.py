@@ -172,6 +172,16 @@ def _live_frames(batch: pkt.PacketBatch) -> List[np.ndarray]:
             for i in np.flatnonzero(batch.valid)]
 
 
+def fetch(nodes: Sequence["Node"]) -> None:
+    """One blocking read of the outputs of every launched step of
+    ``nodes``: ``device_get`` starts every copy before it waits on any, so
+    the wait covers only the device work still queued."""
+    with obs.span("d2h.to_host"):
+        host = jax.device_get([n._launched for n in nodes])
+    for n, out in zip(nodes, host):
+        n._launched, n._fetched = None, out
+
+
 class Node:
     """One endpoint of the fabric: NIC + host engines + a MAC address.
 
@@ -212,6 +222,10 @@ class Node:
         # so duplicates appear under loss; membership, not equality, is the
         # meaningful check.
         self.completions: List[int] = []
+        # a launched step's outputs: on the device until fetched, then
+        # their host copy until the tick that finishes the step
+        self._launched: Optional[tuple] = None
+        self._fetched: Optional[tuple] = None
 
     def tick_idle(self, now: int) -> List[np.ndarray]:
         """Advance one tick with an empty ingress batch.  The NIC step is
@@ -229,19 +243,28 @@ class Node:
                 out.extend(e.poll(now))
         return out
 
+    def launch(self, ingress: pkt.PacketBatch) -> None:
+        """Dispatch the NIC step on ``ingress`` and hold what it gives the
+        host — host-path frames, handler egress and, where handlers may
+        push, the completion FIFO — on the device, unread.  The next
+        :meth:`tick` finishes the step; :func:`fetch` reads the held
+        outputs of many nodes at once before that."""
+        with obs.span("nic.step"):
+            self.state, egress, to_host = self.nic.step(self.state, ingress)
+        fifo = ((self.state.counter_count, self.state.counters)
+                if self._completes else None)
+        self._launched = (to_host, egress, fifo)
+
     def tick(self, ingress: pkt.PacketBatch, now: int) -> List[np.ndarray]:
         """Advance one tick: run the NIC on the delivered ingress batch,
         hand host-path frames and completions to the engines, and return
-        every frame this node puts on the wire."""
-        with obs.span("nic.step"):
-            self.state, egress, to_host = self.nic.step(self.state, ingress)
-
-        # one blocking read of all this step gives the host: device_get
-        # starts every copy before it waits on any
-        fifo = ((self.state.counter_count, self.state.counters)
-                if self._completes else None)
-        with obs.span("d2h.to_host"):
-            to_host, egress, fifo = jax.device_get((to_host, egress, fifo))
+        every frame this node puts on the wire.  A step already
+        :meth:`launch`-ed on ``ingress`` is finished, not run again."""
+        if self._fetched is None:
+            if self._launched is None:
+                self.launch(ingress)
+            fetch([self])
+        (to_host, egress, fifo), self._fetched = self._fetched, None
 
         # host datapath: deliver non-matching frames to the engines
         host_frames = _live_frames(to_host)
@@ -276,6 +299,7 @@ class Node:
         the jitted datapath — sweep benchmarks reuse one Node per config."""
         self.state = self.nic.init_state()
         self.completions = []
+        self._launched = self._fetched = None
         if engines is not None:
             self.engines = list(engines)
 

@@ -11,7 +11,8 @@ letter for letter.  Spans nest; a span's self time is its duration minus
 the spans inside it.  Every blocking device→host read of the tick sits
 inside exactly one ``d2h.*`` span, and each ``d2h.*`` span wraps one wait
 for what one decision of the host needs.  Work done per node is spanned
-per node, never around a loop over nodes.
+per node, never around a loop over nodes; the one read of all launched
+NIC steps (``d2h.to_host``) is a single wait, not a loop.
 
 =====================  ===================================================
 ``link.pop``           dispatch of the link drain, with its argument puts
@@ -19,7 +20,8 @@ per node, never around a loop over nodes.
 ``nic.step``           dispatch of one node's NIC step
 ``nic.write``          dispatch of a small host write into ``NICState``
 ``d2h.ingress``        the host's wait for the delivered ingress batches
-``d2h.to_host``        the one read of a node's NIC-step outputs: host-path
+``d2h.to_host``        the one read a tick of the NIC-step outputs of every
+                       node whose step was launched: host-path
                        (non-matching) frames, handler egress and the
                        completion counter FIFO
 ``d2h.egress``         retired from the program (the egress is read under

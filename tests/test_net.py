@@ -1,15 +1,18 @@
 """Tests for repro.net: link model invariants, MAC routing, two-node
 SLMP reliability under loss, ping-pong, fabric checkpointing, and the
-node's one device read of each NIC step."""
+two-phase fabric tick: every busy node's NIC step launched, then one
+device read for all of them."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import obs
+from repro import mpi, obs
 from repro.core import apps, handlers as H, packet as pkt, slmp
 from repro.net import (Fabric, Link, LinkConfig, Node, PingPongClient,
                        SlmpSenderEngine)
+from repro.net import fabric as fabric_mod
+from repro.net.node import HostEngine
 
 
 def _frames(n, nbytes=32):
@@ -210,11 +213,11 @@ def test_slmp_sender_gives_up_after_max_retries():
     assert sender.failed and not sender.done
 
 
-# ------------------------------------------- one device read a NIC step
+# ------------------------------------------------ the two-phase fabric tick
 def _reference_tick(node, ingress, now):
-    """``Node.tick`` with each of the step's outputs read on its own:
-    valid, then data and length of each batch, and the completion FIFO
-    drained through ``SpinNIC.pop_counters``."""
+    """``Node.tick`` stepping by itself, with each of the step's outputs
+    read on its own: valid, then data and length of each batch, and the
+    completion FIFO drained through ``SpinNIC.pop_counters``."""
     def frames(batch):
         valid = np.asarray(batch.valid)
         if not valid.any():
@@ -240,32 +243,29 @@ def _reference_tick(node, ingress, now):
     return out
 
 
-def _record_ticks(monkeypatch, tick):
-    """Runs ``tick`` as ``Node.tick`` and records what each call returns
-    and the names of the program spans it opens."""
-    calls, opened = [], []
-    span = obs.span
-
-    def counting(name):
-        opened.append(name)
-        return span(name)
-
-    def recording(node, ingress, now):
-        first = len(opened)
-        out = tick(node, ingress, now)
-        calls.append((node.name, now, [f.tobytes() for f in out],
-                      opened[first:]))
-        return out
-    monkeypatch.setattr(obs, "span", counting)
-    monkeypatch.setattr(Node, "tick", recording)
-    return calls
+def _one_node_at_a_time(fab):
+    """``Fabric._tick_batched`` with each busy node stepping, reading and
+    running its engines before the next node steps."""
+    now = fab.now
+    fab._stack, ing = fabric_mod._pop_all(fab._cfg0, fab._batch0,
+                                          fab._stack, now)
+    valid = np.asarray(ing.valid)
+    data, length = np.asarray(ing.data), np.asarray(ing.length)
+    outbound = [[] for _ in fab.nodes]
+    for i, node in enumerate(fab.nodes):
+        if valid[i].any():
+            frames = node.tick(pkt.PacketBatch(data[i], length[i], valid[i]),
+                               now)
+        else:
+            frames = node.tick_idle(now)
+        fab._route(frames, outbound)
+    fab._flush_outbound(outbound)
 
 
-def _lossy_transfers(monkeypatch, tick):
+def _lossy_transfers():
     """Three SLMP messages over a lossy, duplicating, reordering wire: the
     receiver's tail handler pushes a completion on every EOM arrival and
     its ACKs reach the sender's host path."""
-    calls = _record_ticks(monkeypatch, tick)
     rng = np.random.default_rng(0)
     cfg = slmp.SlmpSenderConfig(window=4, mtu_payload=512, timeout=6,
                                 src_mac=pkt.node_mac(0),
@@ -278,37 +278,209 @@ def _lossy_transfers(monkeypatch, tick):
              host_bytes=1 << 14)
     fab = Fabric([a, b], link_cfg=LinkConfig(loss=0.15, latency=2, jitter=2,
                                              duplicate=0.2), seed=3)
-    fab.run(max_ticks=3000)
-    assert all(s.done and not s.failed for s in senders)
-    return fab, calls
+
+    def run():
+        fab.run(max_ticks=3000)
+        assert all(s.done and not s.failed for s in senders)
+        assert len(b.completions) > 3                 # duplicates too
+        return b.completions
+    return fab, run
+
+
+def _allreduce_8r():
+    """A segmented Rabenseifner allreduce of 32 KiB a rank on 8 ranks at
+    2 % loss: 2 KiB segments over the credit-managed rendezvous.  Beside
+    it, rank 1 sends rank 2 a rendezvous chunk whose receive is posted
+    only when rank 0 completes a receive of its own: a completion on rank
+    0 grants the waiting RTS on rank 2 and arms rank 2's expect table."""
+    cfg = mpi.MpiConfig(eager_threshold=1024, eager_slot_bytes=4096,
+                        coll_seg_bytes=2048, n_rdv_slots=4)
+    comm = mpi.Communicator(8, cfg=cfg, seed=5,
+                            link_cfg=LinkConfig(loss=0.02, latency=1))
+    rng = np.random.default_rng(1)
+    vals = [rng.standard_normal(8192).astype(np.float32) for _ in range(8)]
+    chunk = rng.integers(0, 256, cfg.coll_seg_bytes).astype(np.uint8)
+
+    def run():
+        got, late_recv = np.zeros_like(chunk), []
+        comm.isend(1, 2, chunk, tag=7, datatype=comm.seg_dtype)
+        cue = comm.irecv(0, np.zeros(64, np.uint8), source=3, tag=8)
+        cue.add_done_callback(lambda _: late_recv.append(
+            comm.irecv(2, got, source=1, tag=7)))
+        comm.isend(3, 0, np.ones(64, np.uint8), tag=8)
+        h = mpi.iallreduce(comm, vals, algorithm="rab")
+        comm.wait(h, max_ticks=20_000)
+        comm.wait(*late_recv, max_ticks=20_000)
+        np.testing.assert_array_equal(got, chunk)
+        want = np.sum(np.stack(vals), axis=0, dtype=np.float64)
+        for r in h.result:
+            np.testing.assert_allclose(r, want, rtol=1e-5, atol=1e-5)
+        stats = comm.stats()
+        assert sum(s["rdv_sent"] for s in stats) > 0
+        assert sum(s["retransmits"] for s in stats) > 0
+        return dict(ticks=comm.now, stats=stats, links=comm.link_stats(),
+                    result=[r.tobytes() for r in h.result])
+    return comm.fabric, run
 
 
 def _host_tree(tree):
     return [np.asarray(x) for x in jax.tree.leaves(tree)]
 
 
-def test_node_tick_reads_a_step_as_separate_reads_would(monkeypatch):
+def _recorded(monkeypatch, scenario, reference):
+    """Runs ``scenario`` with the two-phase tick, or with the reference
+    (one node at a time, separate reads), and logs tick by tick on every
+    node: the frames it returns, the host-path frames and completions its
+    engines are handed, and after the tick its completions drained so far
+    and every leaf of its NIC state.  Also counts the ``write_expect``
+    calls that land on a node between its step's launch and its finish."""
+    if reference:
+        monkeypatch.setattr(Fabric, "_tick_batched", _one_node_at_a_time)
+        monkeypatch.setattr(Node, "tick", _reference_tick)
+    fab, run = scenario()
+    log, late = [], []
+    tick, tick_idle = Node.tick, Node.tick_idle
+    fabric_tick, write_expect = Fabric.tick, Node.write_expect
+
+    def frames(fs):
+        return [f.tobytes() for f in fs]
+
+    def ticking(node, ingress, now):
+        out = tick(node, ingress, now)
+        log.append(("out", node.name, now, frames(out)))
+        return out
+
+    def idling(node, now):
+        out = tick_idle(node, now)
+        log.append(("out", node.name, now, frames(out)))
+        return out
+
+    def ticked(f):
+        fabric_tick(f)
+        for n in f.nodes:
+            log.append(("state", n.name, f.now, len(n.completions),
+                        [x.tobytes() for x in _host_tree(n.state)]))
+
+    def writing(node, idx, msg_id):
+        late.append(node._fetched is not None)
+        write_expect(node, idx, msg_id)
+
+    def hooked(node, engine, name):
+        call = getattr(engine, name)
+
+        def handed(values, now):
+            got = frames(values) if name == "on_host_frames" \
+                else values.tolist()
+            log.append((name, node.name, now, got))
+            call(values, now)
+        return handed
+
+    monkeypatch.setattr(Node, "tick", ticking)
+    monkeypatch.setattr(Node, "tick_idle", idling)
+    monkeypatch.setattr(Fabric, "tick", ticked)
+    monkeypatch.setattr(Node, "write_expect", writing)
+    for n in fab.nodes:
+        for e in n.engines:
+            for name in ("on_host_frames", "on_completions"):
+                monkeypatch.setattr(e, name, hooked(n, e, name))
+    return fab, run(), log, sum(late)
+
+
+@pytest.mark.parametrize("scenario", (_lossy_transfers, _allreduce_8r),
+                         ids=("slmp_lossy", "allreduce_8r_rdv"))
+def test_two_phase_tick_matches_one_node_at_a_time(monkeypatch, scenario):
+    """Every busy node's step launched, then one read for all of them,
+    then each node finished in order: tick by tick on every node the same
+    frames, host-path deliveries, completions and NIC state as stepping
+    and reading one node at a time with a read for each output."""
     with monkeypatch.context() as m:
-        fused, fused_calls = _lossy_transfers(m, Node.tick)
+        fab, result, log, late = _recorded(m, scenario, reference=False)
     with monkeypatch.context() as m:
-        ref, ref_calls = _lossy_transfers(m, _reference_tick)
-    frames = [c[:3] for c in fused_calls]
-    assert frames == [c[:3] for c in ref_calls]
-    assert sum(len(c[2]) for c in fused_calls) > 0        # egress left
-    recv, ref_recv = fused.node("recv"), ref.node("recv")
-    assert len(recv.completions) > 3                      # duplicates too
-    assert recv.completions == ref_recv.completions
-    for n, r in zip(fused.nodes, ref.nodes):
-        for x, y in zip(_host_tree(n.state), _host_tree(r.state)):
-            np.testing.assert_array_equal(x, y)
+        ref, ref_result, ref_log, _ = _recorded(m, scenario, reference=True)
+    assert result == ref_result
+    assert len(log) == len(ref_log)
+    for got, want in zip(log, ref_log):
+        assert got == want, got[:3]
+    assert {entry[0] for entry in log} >= {"out", "state", "on_host_frames"}
+    assert any(e[0] == "out" and e[3] for e in log)       # egress left
+    assert any(n.completions for n in fab.nodes)
+    for n, r in zip(fab.nodes, ref.nodes):
+        assert n.completions == r.completions
+    if scenario is _allreduce_8r:
+        # a completion on rank 0 armed rank 2's expect table after rank
+        # 2's step was launched, where the reference wrote it before
+        # that step
+        assert late > 0
+
+
+def _spans_by_tick(monkeypatch):
+    """The program spans each ``Fabric.tick`` opens, one list a tick."""
+    ticks, opened = [], []
+    span, tick = obs.span, Fabric.tick
+
+    def counting(name):
+        opened.append(name)
+        return span(name)
+
+    def ticked(fab):
+        first = len(opened)
+        tick(fab)
+        ticks.append(opened[first:])
+    monkeypatch.setattr(obs, "span", counting)
+    monkeypatch.setattr(Fabric, "tick", ticked)
+    return ticks
 
 
 def test_busy_node_tick_opens_one_read_span(monkeypatch):
-    _, calls = _lossy_transfers(monkeypatch, Node.tick)
-    reads = [[n for n in c[3] if n.startswith("d2h.")] for c in calls]
-    assert calls and all(r == ["d2h.to_host"] for r in reads)
+    """On the lossy transfers, a tick with any busy node reads the
+    ingress pair and then the steps' outputs under one ``d2h.to_host``,
+    with one or both nodes busy; an idle tick reads only ``valid``."""
+    ticks = _spans_by_tick(monkeypatch)
+    _, run = _lossy_transfers()
+    run()
+    assert ticks
+    busy_counts = set()
+    for spans in ticks:
+        busy = spans.count("nic.step")
+        busy_counts.add(busy)
+        reads = [n for n in spans if n.startswith("d2h.")]
+        assert reads == (["d2h.ingress", "d2h.ingress", "d2h.to_host"]
+                         if busy else ["d2h.ingress"])
+    assert busy_counts == {0, 1, 2}
     # some of these ticks drained completions
-    assert any("engine.completions" in c[3] for c in calls)
+    assert any("engine.completions" in spans for spans in ticks)
+
+
+class _Once(HostEngine):
+    """Puts one frame on the wire at its first poll."""
+
+    def __init__(self, frame):
+        self.frame = frame
+
+    def poll(self, now):
+        out, self.frame = [] if self.frame is None else [self.frame], None
+        return out
+
+
+@pytest.mark.parametrize("n_busy", (1, 2, 8))
+def test_fabric_tick_reads_every_busy_step_at_once(monkeypatch, n_busy):
+    """Of 8 nodes, ``n_busy`` receive a frame on the same tick: that tick
+    launches ``n_busy`` NIC steps and opens one ``d2h.to_host``; a tick
+    with no busy node opens none."""
+    ticks = _spans_by_tick(monkeypatch)
+    macs = [pkt.node_mac(i) for i in range(8)]
+    nodes = [Node(f"n{i}", macs[i], [apps.make_null_context()], batch=8,
+                  engines=[_Once(pkt.make_udp(
+                      np.arange(32, dtype=np.uint8), src_mac=macs[i],
+                      dst_mac=macs[(i + 1) % 8]))] if i < n_busy else [])
+             for i in range(8)]
+    fab = Fabric(nodes, link_cfg=LinkConfig(loss=0.0, latency=1), seed=0)
+    for _ in range(3):
+        fab.tick()
+    steps = [spans.count("nic.step") for spans in ticks]
+    reads = [spans.count("d2h.to_host") for spans in ticks]
+    assert steps == [0, n_busy, 0]
+    assert reads == [0, 1, 0]
 
 
 def test_node_tick_drains_an_overrun_fifo_as_pop_counters():
