@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python examples/serve_decode.py [arch]
 
-Runs the same prefill/serve_step programs the multi-pod dry-run lowers
-for the decode_32k / long_500k cells (there with 256/512-chip shardings).
+Runs the prefill/serve_step programs of the decode_32k / long_500k shape
+suites at smoke size.
 """
 import sys
 sys.path.insert(0, "src")

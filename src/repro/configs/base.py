@@ -83,10 +83,6 @@ class ModelConfig:
     # over the model axis even for awkward vocab sizes (§Perf H3; padded
     # logit lanes are masked to -inf in lm_logits)
     pad_vocab_multiple: int = 0
-    # diagnostic: skip the sequence mixer (attention/ssm/rglru) entirely —
-    # used by the roofline ablation to attribute HBM bytes to attention
-    # (never a training configuration)
-    ablate_mixer: bool = False
 
     # ---------------------------------------------------------- helpers
     @property
@@ -123,8 +119,7 @@ class ModelConfig:
     def moe_layer(self, layer_idx: int) -> bool:
         return self.family == "moe" and layer_idx >= self.first_k_dense
 
-    # Parameter count (analytic; used by roofline MODEL_FLOPS and memory
-    # accounting).  Counts all trainable params.
+    # Parameter count (analytic).  Counts all trainable params.
     def param_count(self, active_only: bool = False) -> int:
         d, v = self.d_model, self.vocab
         emb = v * d * (1 if self.tie_embeddings else 2)

@@ -12,7 +12,7 @@ Four shapes per architecture (40 cells):
 
 ``input_specs`` returns weak-type-correct ``jax.ShapeDtypeStruct``
 stand-ins (or concrete arrays for smoke tests) for every model input —
-no device allocation during the dry-run.  Modality frontends are stubs:
+no device allocation for abstract shapes.  Modality frontends are stubs:
 whisper gets precomputed frame embeddings, qwen2-vl gets patch embeddings
 and M-RoPE position ids.
 """

@@ -1,9 +1,9 @@
 """Pallas TPU flash-attention kernel (forward): the §Perf answer to the
 HLO attention floor.
 
-The dry-run showed (EXPERIMENTS §Perf, iterations H4/H5) that ~80 % of a
-train cell's memory term is S²-shaped score/probability traffic that HLO
-*must* materialize between the QKᵀ and PV dots.  A fused kernel keeps
+About 80 % of an LLM train step's memory term is S²-shaped
+score/probability traffic that HLO *must* materialize between the QKᵀ
+and PV dots.  A fused kernel keeps
 those blocks in VMEM: HBM sees only Q, K, V, O — the flash-attention
 trade.  This kernel implements the online-softmax streaming form with
 explicit BlockSpec tiling:

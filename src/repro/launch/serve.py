@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
         --batch 4 --prompt-len 32 --gen 16
 
-Uses the same model/caches the dry-run lowers for the decode cells; on a
-real pod the params/caches carry the shardings of parallel/sharding.py.
+On a real pod the params/caches carry the shardings of
+parallel/sharding.py.
 """
 from __future__ import annotations
 

@@ -9,9 +9,7 @@ offloaded datatype processing) double-buffered against the train step →
 atomic checkpoints → fault supervisor with bounded restarts.
 
 ``--smoke`` selects the reduced same-family config (CPU-runnable);
-omitting it uses the full assigned architecture (real-cluster scale; on
-this host only the dry-run path makes sense for those — see
-launch/dryrun.py).
+omitting it uses the full assigned architecture (real-cluster scale).
 """
 from __future__ import annotations
 

@@ -29,15 +29,6 @@ from repro.models import layers as L
 
 NEG_INF = -1e30
 
-# Roofline/dry-run mode: unroll the q/kv block loops statically instead of
-# lax.map/lax.scan, so compiled.cost_analysis() counts every block (scan
-# bodies are otherwise costed once) AND statically skips fully-masked
-# blocks — giving exact sparse FLOP counts for causal/windowed attention.
-# Runtime semantics are identical; launch/dryrun.py flips this before
-# lowering.  Never enabled on the training/serving hot path.
-STATIC_BLOCKS = False
-
-
 def attn_init(key, cfg: ModelConfig, cross: bool = False):
     d, dt = cfg.d_model, jnp.dtype(cfg.dtype)
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -195,31 +186,13 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
         m0 = jnp.full((b, kvh, g, bq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kvh, g, bq), jnp.float32)
         a0 = jnp.zeros((b, kvh, g, bq, d), jnp.float32)
-        if STATIC_BLOCKS:
-            carry = (m0, l0, a0)
-            qi_static = int(qi)            # static under unrolled path
-            for ki in range(nk):
-                # static skip of fully-masked blocks (exact sparse flops)
-                if causal and ki * bk > qi_static * bq + bq - 1:
-                    continue
-                if window > 0 and ki * bk + bk - 1 <= qi_static * bq \
-                        - window:
-                    continue
-                carry, _ = kv_step(carry,
-                                   (jnp.asarray(ki), kb[ki], vb[ki]))
-            m, l, acc = carry
-        else:
-            (m, l, acc), _ = jax.lax.scan(
-                kv_step, (m0, l0, a0),
-                (jnp.arange(nk), kb, vb))
+        (m, l, acc), _ = jax.lax.scan(
+            kv_step, (m0, l0, a0), (jnp.arange(nk), kb, vb))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out.astype(q.dtype)
 
-    if STATIC_BLOCKS:
-        outs = jnp.stack([one_q_block(qi, qb[qi]) for qi in range(nq)])
-    else:
-        outs = jax.lax.map(lambda args: one_q_block(*args),
-                           (jnp.arange(nq), qb))
+    outs = jax.lax.map(lambda args: one_q_block(*args),
+                       (jnp.arange(nq), qb))
     # (nq, B, KV, G, bq, D) -> (B, Sq, H, D)
     out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq_p, h, d)
     return out[:, :sq]

@@ -2,8 +2,8 @@
 
 All layers are pure functions over parameter pytrees (dicts).  Init
 functions only build ``jax.ShapeDtypeStruct``-compatible shapes through
-``jax.eval_shape`` when used by the dry-run, so nothing here may allocate
-eagerly at import time.
+``jax.eval_shape`` when used for sharding specs, so nothing here may
+allocate eagerly at import time.
 """
 from __future__ import annotations
 

@@ -76,10 +76,7 @@ def _block_apply_train(p, cfg: ModelConfig, kind: str, h, positions,
     is written into it using decode-compatible addressing."""
     aux = jnp.zeros((), jnp.float32)
     x = L.rmsnorm(p["norm1"], h, cfg.norm_eps)
-    if cfg.ablate_mixer:
-        # roofline diagnostic: mixer bytes are attributed by difference
-        pass
-    elif kind in ("attn", "local"):
+    if kind in ("attn", "local"):
         if cache is not None:
             y, (k, v) = A.attend_train(p["attn"], cfg, x, positions,
                                        kind=kind, return_kv=True)
@@ -266,7 +263,7 @@ class Model:
         return params
 
     def init_eval(self) -> Params:
-        """Abstract init (ShapeDtypeStructs) — used by the dry-run."""
+        """Abstract init (ShapeDtypeStructs), for sharding specs."""
         return jax.eval_shape(self.init, jax.random.key(0))
 
     # ------------------------------------------------------------- forward

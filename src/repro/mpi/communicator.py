@@ -169,7 +169,6 @@ class Communicator:
     def __init__(self, n_ranks: int,
                  registry: Optional[DatatypeRegistry] = None,
                  link_cfg: LinkConfig = LinkConfig(latency=2),
-                 link_cfgs: Optional[Sequence[LinkConfig]] = None,
                  seed: int = 0, cfg: MpiConfig = MpiConfig()):
         assert n_ranks >= 1
         self.n_ranks = n_ranks
@@ -250,9 +249,7 @@ class Communicator:
             self.engines.append(engine)
             self.nodes.append(node)
         self.link_cfg = link_cfg
-        self.link_cfgs = list(link_cfgs) if link_cfgs is not None else None
-        self.fabric = Fabric(self.nodes, link_cfg=link_cfg,
-                             link_cfgs=self.link_cfgs, seed=seed)
+        self.fabric = Fabric(self.nodes, link_cfg=link_cfg, seed=seed)
 
     # ------------------------------------------------------------ plumbing
     @property
@@ -260,15 +257,11 @@ class Communicator:
         return self.fabric.now
 
     def rewire(self, link_cfg: Optional[LinkConfig] = None,
-               link_cfgs: Optional[Sequence[LinkConfig]] = None,
                seed: int = 0) -> None:
-        """Fresh engines/NIC-states/links (optionally new link configs)
+        """Fresh engines/NIC-states/links (optionally a new link config)
         without recompiling the shared datapath — sweeps reuse one comm."""
         if link_cfg is not None:
             self.link_cfg = link_cfg
-            self.link_cfgs = None
-        if link_cfgs is not None:
-            self.link_cfgs = list(link_cfgs)
         self.pool = BufferPool()
         self._plans = {}
         self._next_plan_id = 0
@@ -279,8 +272,7 @@ class Communicator:
             node.reset(engines=[engine])
             engine.attach(node)
             self.engines.append(engine)
-        self.fabric = Fabric(self.nodes, link_cfg=self.link_cfg,
-                             link_cfgs=self.link_cfgs, seed=seed)
+        self.fabric = Fabric(self.nodes, link_cfg=self.link_cfg, seed=seed)
 
     def reset(self, seed: int = 0) -> None:
         self.rewire(seed=seed)

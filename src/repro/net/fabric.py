@@ -8,21 +8,19 @@ ingress batch.  One :meth:`Fabric.tick` advances every node by one NIC
 step plus one link round — discrete-event at batch granularity, the same
 granularity as ``SpinNIC.step``.
 
-**Hot loop.** When every link shares one config and every node one batch
-size (the common case — an MPI job, a benchmark sweep), the per-tick work
-is batched across nodes: one vmapped ``pop`` drains all N links in a
-single device call, destination MACs of all egress frames are matched
-against the node-MAC matrix in one vectorized compare (no per-frame
-``bytes()``/dict hops), and all routed traffic lands on the links through
-one vmapped ``push``.  Every node whose link delivered frames has its
-NIC step launched first (``Node.launch``), in node order; one
-``device_get`` then reads the outputs of all of them (``fetch``), so one
-read serves every busy node, and only then does each node, in order,
-hand its frames and completions to its engines (``Node.tick``).  Nodes
-whose link delivered nothing this tick skip the NIC step entirely
-(``Node.tick_idle``) — on a mostly-idle fabric the tick cost is one pop,
-N cheap engine polls, and at most one push.
-Heterogeneous ``link_cfgs`` / batch sizes fall back to the per-link loop.
+**Hot loop.** Every link shares one config and every node one batch
+size, so the per-tick work is batched across nodes: one vmapped ``pop``
+drains all N links in a single device call, destination MACs of all
+egress frames are matched against the node-MAC matrix in one vectorized
+compare (no per-frame ``bytes()``/dict hops), and all routed traffic
+lands on the links through one vmapped ``push``.  Every node whose link
+delivered frames has its NIC step launched first (``Node.launch``), in
+node order; one ``device_get`` then reads the outputs of all of them
+(``fetch``), so one read serves every busy node, and only then does each
+node, in order, hand its frames and completions to its engines
+(``Node.tick``).  Nodes whose link delivered nothing this tick skip the
+NIC step entirely (``Node.tick_idle``) — on a mostly-idle fabric the tick
+cost is one pop, N cheap engine polls, and at most one push.
 
 The whole system state (per-node ``NICState``, per-link ``LinkState``,
 host-engine counters, the tick clock, the PRNG key) is captured by
@@ -32,7 +30,7 @@ pure function of (initial state, seed), like a single NIC.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Sequence
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -44,8 +42,8 @@ from repro.net import link as linklib
 from repro.net.node import Node, fetch
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _pop_all(cfg: linklib.LinkConfig, n: int, states, now):
+@functools.partial(jax.jit, static_argnums=(0,))
+def _pop_all(n: int, states, now):
     """Drain all N links at once: one device call instead of N."""
     return jax.vmap(lambda s: linklib._pop(s, now, n))(states)
 
@@ -58,48 +56,34 @@ def _push_all(cfg: linklib.LinkConfig, states, keys, batch, now):
         lambda s, k, b: linklib._push(cfg, s, k, b, now))(states, keys, batch)
 
 
+def _stacked(states: Sequence[linklib.LinkState]) -> linklib.LinkState:
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+
+
 class Fabric:
     def __init__(self, nodes: Sequence[Node],
                  link_cfg: linklib.LinkConfig = linklib.LinkConfig(),
-                 link_cfgs: Optional[Sequence[linklib.LinkConfig]] = None,
                  seed: int = 0):
-        """``link_cfgs`` (one per node, ingress side) overrides the shared
-        ``link_cfg`` when per-node asymmetry is wanted."""
+        """Every node's ingress link runs ``link_cfg``; every node must
+        step the same ``batch`` of frames a tick."""
         self.nodes: List[Node] = list(nodes)
-        cfgs = list(link_cfgs) if link_cfgs is not None else \
-            [link_cfg] * len(self.nodes)
-        assert len(cfgs) == len(self.nodes)
-        self.links = [linklib.Link(c) for c in cfgs]
+        batches = sorted({n.batch for n in self.nodes})
+        if len(batches) != 1:
+            raise ValueError(
+                f"fabric nodes must share one batch size, got {batches}")
+        self.link_cfg = link_cfg
+        self.batch = batches[0]
         self.key = jax.random.PRNGKey(seed)
         self.now = 0
         self.unroutable = 0
-        self._by_mac: Dict[bytes, int] = {
-            n.mac: i for i, n in enumerate(self.nodes)}
         # (N, 6) MAC matrix for the vectorized routing compare
         self._mac_mat = np.stack(
             [np.frombuffer(n.mac, np.uint8) for n in self.nodes])
-        # uniform fast path: identical link cfgs + identical node batches
-        self._uniform = (len(set(cfgs)) == 1
-                         and len({n.batch for n in self.nodes}) == 1)
-        if self._uniform:
-            self._cfg0 = cfgs[0]
-            self._batch0 = self.nodes[0].batch
-            self._stack = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[l.init_state() for l in self.links])
-            self.link_states = None
-        else:
-            self._stack = None
-            self.link_states = [l.init_state() for l in self.links]
+        # every link's state, stacked on a leading node axis
+        self._stack = _stacked(
+            [linklib.make_state(link_cfg.capacity)] * len(self.nodes))
 
     # ---------------------------------------------------------------- tick
-    def tick(self) -> None:
-        if self._uniform:
-            self._tick_batched()
-        else:
-            self._tick_loop()
-        self.now += 1
-
     def _route(self, frames: List[np.ndarray],
                outbound: List[List[np.ndarray]]) -> None:
         """Vectorized MAC routing: match every frame's destination MAC
@@ -115,11 +99,10 @@ class Fabric:
             for i in np.flatnonzero(ok):
                 outbound[dest[i]].append(frames[i])
 
-    def _tick_batched(self) -> None:
+    def tick(self) -> None:
         now = self.now
         with obs.span("link.pop"):
-            self._stack, ing = _pop_all(self._cfg0, self._batch0,
-                                        self._stack, now)
+            self._stack, ing = _pop_all(self.batch, self._stack, now)
         # one host sync for the whole fabric: materialize the delivered
         # batches as numpy (a few tens of KB) — per-node numpy slices are
         # free, where N eager device slices would each pay a dispatch
@@ -146,6 +129,7 @@ class Fabric:
                 frames = node.tick_idle(now)
             self._route(frames, outbound)
         self._flush_outbound(outbound)
+        self.now += 1
 
     def _flush_outbound(self, outbound: List[List[np.ndarray]]) -> None:
         """Admit routed per-node egress onto all links in one vmapped
@@ -169,80 +153,34 @@ class Fabric:
             self.key, sub = jax.random.split(self.key)
             keys = jax.random.split(sub, n_nodes)
             self._stack = _push_all(
-                self._cfg0, self._stack, keys,
+                self.link_cfg, self._stack, keys,
                 pkt.PacketBatch(jnp.asarray(data), jnp.asarray(length),
                                 jnp.asarray(ok)), self.now)
-
-    def _tick_loop(self) -> None:
-        """Per-link fallback for heterogeneous link configs/batches."""
-        now = self.now
-        outbound: List[List[np.ndarray]] = [[] for _ in self.nodes]
-        for i, node in enumerate(self.nodes):
-            with obs.span("link.pop"):
-                self.link_states[i], ingress = self.links[i].pop(
-                    self.link_states[i], now, node.batch)
-            frames = node.tick(ingress, now)
-            self._route(frames, outbound)
-        for j, frames in enumerate(outbound):
-            if not frames:
-                continue
-            n = 1 << max(0, (len(frames) - 1).bit_length())
-            with obs.span("link.push"):
-                self.key, sub = jax.random.split(self.key)
-                self.link_states[j] = self.links[j].push(
-                    self.link_states[j], sub, pkt.stack_frames(frames, n=n),
-                    now)
 
     def run(self, max_ticks: int = 10_000, until=None) -> int:
         """Tick until ``until()`` (default: every node's engines done and
         all links drained) or ``max_ticks``.  Returns ticks executed."""
         if until is None:
             def until():
-                if not all(n.done for n in self.nodes):
-                    return False
-                if self._uniform:
-                    return not bool(
-                        np.asarray(self._stack.occupied).any())
-                return not any(bool(np.asarray(s.occupied).any())
-                               for s in self.link_states)
+                return all(n.done for n in self.nodes) and not bool(
+                    np.asarray(self._stack.occupied).any())
         t0 = self.now
         while self.now - t0 < max_ticks and not until():
             self.tick()
         return self.now - t0
 
-    def reset(self, seed: int = 0) -> None:
-        """Fresh links/clock/PRNG (node NIC states reset via Node.reset)."""
-        if self._uniform:
-            self._stack = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[l.init_state() for l in self.links])
-        else:
-            self.link_states = [l.init_state() for l in self.links]
-        self.key = jax.random.PRNGKey(seed)
-        self.now = 0
-        self.unroutable = 0
-
     # ---------------------------------------------------------- observability
     def node(self, name: str) -> Node:
         return next(n for n in self.nodes if n.name == name)
 
-    def _per_link_states(self) -> List[linklib.LinkState]:
-        if self._uniform:
-            return [jax.tree.map(lambda a, i=i: a[i], self._stack)
-                    for i in range(len(self.nodes))]
-        return self.link_states
-
     def link_stats(self) -> List[dict]:
         with obs.span("d2h.link_stats"):
-            if self._uniform:
-                # one transfer per counter for the whole fabric
-                names = ("pushed", "lost", "overflowed", "duplicated",
-                         "reordered", "delivered", "deferred")
-                cols = {k: np.asarray(getattr(self._stack, k))
-                        for k in names}
-                return [{k: int(cols[k][i]) for k in names}
-                        for i in range(len(self.nodes))]
-            return [l.stats(s) for l, s in zip(self.links, self.link_states)]
+            # one transfer per counter for the whole fabric
+            names = ("pushed", "lost", "overflowed", "duplicated",
+                     "reordered", "delivered", "deferred")
+            cols = {k: np.asarray(getattr(self._stack, k)) for k in names}
+            return [{k: int(cols[k][i]) for k in names}
+                    for i in range(len(self.nodes))]
 
     def stats(self) -> dict:
         """Fabric-wide health: unroutable frames (frames whose destination
@@ -259,8 +197,8 @@ class Fabric:
             now=self.now,
             key=jnp.copy(self.key),
             unroutable=self.unroutable,
-            links=[jax.tree.map(jnp.copy, s)
-                   for s in self._per_link_states()],
+            links=[jax.tree.map(lambda a, i=i: a[i], self._stack)
+                   for i in range(len(self.nodes))],
             nodes=[n.snapshot() for n in self.nodes],
         )
 
@@ -268,12 +206,6 @@ class Fabric:
         self.now = snap["now"]
         self.key = jnp.copy(snap["key"])
         self.unroutable = snap["unroutable"]
-        if self._uniform:
-            self._stack = jax.tree.map(
-                lambda *xs: jnp.stack([jnp.copy(x) for x in xs]),
-                *snap["links"])
-        else:
-            self.link_states = [jax.tree.map(jnp.copy, s)
-                                for s in snap["links"]]
+        self._stack = _stacked(snap["links"])
         for n, s in zip(self.nodes, snap["nodes"]):
             n.restore(s)

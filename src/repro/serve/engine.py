@@ -1,9 +1,9 @@
 """Serving engine: prefill + greedy decode over the model zoo's caches.
 
 Jitted once per (model, batch, max_len); decode donates the cache (in-place
-on device).  This is the single-host form of the engine the decode-cell
-dry-runs lower for 256/512 chips (cache shardings from
-parallel/sharding.py, incl. sequence-sharded long-context caches).
+on device).  This is the single-host form of the engine; a multi-chip
+form takes its cache shardings from parallel/sharding.py (incl.
+sequence-sharded long-context caches).
 """
 from __future__ import annotations
 

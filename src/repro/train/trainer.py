@@ -5,8 +5,7 @@ The step function is built once per (model, mesh, flags):
 
   * mesh=None  — single-device path (CPU examples/tests);
   * mesh given — pjit with parameter/optimizer/batch shardings from
-    parallel/sharding.py (this is also exactly what launch/dryrun.py
-    lowers for the 40 assigned cells);
+    parallel/sharding.py;
   * microbatches > 1 — ``lax.scan`` gradient accumulation inside the step
     (global batch stays the assigned size; activation memory drops by the
     microbatch factor);

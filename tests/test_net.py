@@ -154,6 +154,13 @@ def test_fabric_unroutable_frames_counted():
     assert fab.unroutable > 0
 
 
+def test_fabric_rejects_nodes_of_unequal_batch():
+    a = Node("a", pkt.node_mac(0), [apps.make_null_context()], batch=8)
+    b = Node("b", pkt.node_mac(1), [apps.make_null_context()], batch=16)
+    with pytest.raises(ValueError, match=r"\[8, 16\]"):
+        Fabric([a, b])
+
+
 def test_fabric_pingpong_rtt():
     client = PingPongClient(count=3, proto="udp", src_mac=pkt.node_mac(0),
                             dst_mac=pkt.node_mac(1))
@@ -244,11 +251,10 @@ def _reference_tick(node, ingress, now):
 
 
 def _one_node_at_a_time(fab):
-    """``Fabric._tick_batched`` with each busy node stepping, reading and
-    running its engines before the next node steps."""
+    """``Fabric.tick`` with each busy node stepping, reading and running
+    its engines before the next node steps."""
     now = fab.now
-    fab._stack, ing = fabric_mod._pop_all(fab._cfg0, fab._batch0,
-                                          fab._stack, now)
+    fab._stack, ing = fabric_mod._pop_all(fab.batch, fab._stack, now)
     valid = np.asarray(ing.valid)
     data, length = np.asarray(ing.data), np.asarray(ing.length)
     outbound = [[] for _ in fab.nodes]
@@ -260,6 +266,7 @@ def _one_node_at_a_time(fab):
             frames = node.tick_idle(now)
         fab._route(frames, outbound)
     fab._flush_outbound(outbound)
+    fab.now += 1
 
 
 def _lossy_transfers():
@@ -335,7 +342,7 @@ def _recorded(monkeypatch, scenario, reference):
     and every leaf of its NIC state.  Also counts the ``write_expect``
     calls that land on a node between its step's launch and its finish."""
     if reference:
-        monkeypatch.setattr(Fabric, "_tick_batched", _one_node_at_a_time)
+        monkeypatch.setattr(Fabric, "tick", _one_node_at_a_time)
         monkeypatch.setattr(Node, "tick", _reference_tick)
     fab, run = scenario()
     log, late = [], []

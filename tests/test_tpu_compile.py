@@ -109,7 +109,7 @@ def test_fabric_pop_push_compile_for_8_links(one_chip):
         lambda *xs: jnp.stack(xs),
         *[linklib.make_state(cfg.capacity) for _ in range(n_links)])))
     now = _spec(one_chip, (), jnp.int32)
-    fabriclib._pop_all.lower(cfg, batch, stack, now).compile()
+    fabriclib._pop_all.lower(batch, stack, now).compile()
     keys = _spec(one_chip, (n_links, 2), jnp.uint32)
     egress = pkt.PacketBatch(_spec(one_chip, (n_links, p, pkt.MTU), jnp.uint8),
                              _spec(one_chip, (n_links, p), jnp.int32),
