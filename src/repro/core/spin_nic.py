@@ -282,9 +282,8 @@ class SpinNIC:
         telling the sender to fire, so a recycled DMA region only accepts
         frames of its current occupant."""
         with obs.span("nic.write"):
-            return dataclasses.replace(
-                state, expect=state.expect.at[idx].set(
-                    jnp.uint32(msg_id)))
+            return dataclasses.replace(state, expect=_write_expect(
+                state.expect, np.array([idx, msg_id], np.uint32)))
 
     def read_host(self, state: NICState, base: int, nbytes: int
                   ) -> np.ndarray:
@@ -313,8 +312,22 @@ class SpinNIC:
     def clear_counters(self, state: NICState, queue: int) -> NICState:
         """Host MMIO: reset a counter FIFO's count once it is drained."""
         with obs.span("nic.write"):
-            return dataclasses.replace(
-                state, counter_count=state.counter_count.at[queue].set(0))
+            return dataclasses.replace(state, counter_count=_clear_counters(
+                state.counter_count, queue))
+
+
+# Host writes into NIC state.  Each is one compiled program: run eagerly,
+# an ``.at[].set`` launches five small programs and uploads twice.  Neither
+# donates its input, so a caller may keep the state it wrote into.
+@jax.jit
+def _write_expect(expect: jax.Array, idx_msg: jax.Array) -> jax.Array:
+    """``expect`` with slot ``idx_msg[0]`` set to ``idx_msg[1]``."""
+    return expect.at[idx_msg[0]].set(idx_msg[1])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _clear_counters(counter_count: jax.Array, queue: int) -> jax.Array:
+    return counter_count.at[queue].set(0)
 
 
 def drained(ring: np.ndarray, count: int) -> np.ndarray:

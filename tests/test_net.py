@@ -1,14 +1,18 @@
 """Tests for repro.net: link model invariants, MAC routing, two-node
 SLMP reliability under loss, ping-pong, fabric checkpointing, and the
 two-phase fabric tick: every busy node's NIC step launched, then one
-device read for all of them."""
+device read for all of them; and the host's writes into NIC state, one
+program each."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import mpi, obs
-from repro.core import apps, handlers as H, packet as pkt, slmp
+from repro.core import apps, handlers as H, packet as pkt, slmp, spin_nic
+from repro.mpi import wire
 from repro.net import (Fabric, Link, LinkConfig, Node, PingPongClient,
                        SlmpSenderEngine)
 from repro.net import fabric as fabric_mod
@@ -516,3 +520,76 @@ def test_node_tick_drains_an_overrun_fifo_as_pop_counters():
     assert int(node.state.counter_count[slmp.COMPLETION_QUEUE]) == 0
     node.tick(empty, now=1)                    # a drain is not a peek
     assert node.completions == want.tolist()
+
+
+# ------------------------------------------------- host writes into NIC state
+def _written_nic():
+    """A NIC whose expect table and counter counts hold distinct non-zero
+    words, so a write that touches the wrong slot shows."""
+    nic = spin_nic.SpinNIC([apps.make_mpi_ddt_context(
+        np.zeros((1, 8), np.int32), np.ones(1, np.int32), region_bytes=64,
+        n_slots=8, port=9331)], host_bytes=1 << 10)
+    st = nic.init_state()
+    n = st.expect.shape[0]
+    return nic, dataclasses.replace(
+        st, expect=jnp.asarray(np.arange(1, n + 1) * 0x01010101, jnp.uint32),
+        counter_count=jnp.arange(5, 5 + H.N_COUNTER_QUEUES,
+                                 dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("msg_id", (
+    0, 1, wire.pack_msg_id(wire.MPI_KIND_RDV, 3, 0xABC), 0xFFFFFFFF),
+    ids=("disarm", "one", "packed", "uint32_max"))
+def test_write_expect_matches_the_eager_write(msg_id):
+    """Every slot, armed with every kind of uint32 msg_id, as the eager
+    ``.at[].set`` sets it; the state written into stays readable."""
+    nic, st = _written_nic()
+    before = np.asarray(st.expect)
+    assert before.size == 8
+    for idx in range(before.size):
+        got = nic.write_expect(st, idx, msg_id)
+        want = st.expect.at[idx].set(jnp.uint32(msg_id))
+        assert got.expect.dtype == jnp.uint32
+        np.testing.assert_array_equal(np.asarray(got.expect),
+                                      np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(st.expect), before)
+        assert got.counter_count is st.counter_count
+
+
+@pytest.mark.parametrize("queue", range(H.N_COUNTER_QUEUES))
+def test_clear_counters_matches_the_eager_write(queue):
+    nic, st = _written_nic()
+    before = np.asarray(st.counter_count)
+    got = nic.clear_counters(st, queue)
+    np.testing.assert_array_equal(np.asarray(got.counter_count),
+                                  np.asarray(st.counter_count.at[queue].set(0)))
+    np.testing.assert_array_equal(np.asarray(st.counter_count), before)
+    assert got.expect is st.expect
+
+
+@pytest.mark.parametrize("write,uploads", (
+    (lambda nic, st: nic.write_expect(st, 5, 0xFFFFFFFF), 1),
+    (lambda nic, st: nic.clear_counters(st, slmp.COMPLETION_QUEUE), 0)),
+    ids=("write_expect", "clear_counters"))
+def test_host_write_executes_one_program(tmp_path, write, uploads):
+    """A warm host write into NIC state, under the profiler: the events
+    inside its ``nic.write`` span hold one program execution and at most
+    the one upload of its operands, not a chain of eager primitives."""
+    nic, st = _written_nic()
+    jax.block_until_ready(write(nic, st))             # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(write(nic, st))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = [ev for plane in jax.profiler.ProfileData.from_file(
+        str(path)).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events]
+    (span,) = [ev for ev in events if ev.name == "nic.write"]
+    inside = [ev.name for ev in events
+              if span.start_ns <= ev.start_ns < span.start_ns
+              + span.duration_ns]
+    assert sum(n.endswith("Executable::Execute") for n in inside) == 1, \
+        inside
+    assert inside.count("DevicePut") == uploads, inside
