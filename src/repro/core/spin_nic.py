@@ -291,6 +291,15 @@ class SpinNIC:
         with obs.span("d2h.host_window"):
             return np.asarray(state.host[base:base + nbytes])
 
+    def read_named(self, state: NICState, base: int, offsets: jax.Array
+                   ) -> np.ndarray:
+        """Host read of the window's bytes at ``base + offsets`` alone: one
+        device gather, so a receive whose datatype spans far more bytes
+        than it names reads what it names and not its extent."""
+        with obs.span("d2h.host_window"):
+            return np.asarray(_read_named(state.host, offsets,
+                                          np.int32(base)))
+
     def pop_counters(self, state: NICState, queue: int
                      ) -> Tuple[np.ndarray, NICState]:
         """Drain a counter FIFO (host side).
@@ -328,6 +337,13 @@ def _write_expect(expect: jax.Array, idx_msg: jax.Array) -> jax.Array:
 @functools.partial(jax.jit, static_argnums=1)
 def _clear_counters(counter_count: jax.Array, queue: int) -> jax.Array:
     return counter_count.at[queue].set(0)
+
+
+@jax.jit
+def _read_named(host: jax.Array, offsets: jax.Array,
+                base: jax.Array) -> jax.Array:
+    """``host[base + offsets]``: the bytes a datatype names in one slot."""
+    return jnp.take(host, base + offsets)
 
 
 def drained(ring: np.ndarray, count: int) -> np.ndarray:

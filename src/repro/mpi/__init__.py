@@ -8,7 +8,8 @@ real multi-node experiment).
                    closure-free checkpointable protocol state
   communicator.py  ranks ↔ fabric nodes, requests, progress, checkpoint
   collectives.py   nonblocking plan-based collectives: binomial trees,
-                   recursive-doubling allreduce, Bruck alltoall(v)
+                   recursive-doubling allreduce, Bruck alltoall(v), NPB
+                   MG's comm3 halo exchange
 
 Quick taste::
 
@@ -30,8 +31,9 @@ from repro.mpi.collectives import (ALLREDUCE_RAB_MIN_BYTES,
                                    ALLTOALL_BRUCK_MAX_BLOCK,
                                    BCAST_PIPELINE_MIN_BYTES, CollRequest,
                                    allreduce, alltoall, alltoallv, barrier,
-                                   bcast, iallreduce, ialltoall, ialltoallv,
-                                   ibarrier, ibcast, ireduce, reduce)
+                                   bcast, comm3, iallreduce, ialltoall,
+                                   ialltoallv, ibarrier, ibcast, icomm3,
+                                   ireduce, reduce)
 from repro.mpi.communicator import (COLL_TAG_BASE, BufferPool, Communicator,
                                     MpiConfig, PersistentRequest,
                                     clear_nic_cache)
@@ -45,7 +47,7 @@ __all__ = ["Communicator", "MpiConfig", "DatatypeRegistry", "MpiHostEngine",
            "ANY_SOURCE", "ANY_TAG",
            "bcast", "reduce", "allreduce", "alltoall", "alltoallv",
            "barrier", "ibcast", "ireduce", "iallreduce", "ialltoall",
-           "ialltoallv", "ibarrier", "COLL_TAG_BASE",
+           "ialltoallv", "ibarrier", "comm3", "icomm3", "COLL_TAG_BASE",
            "ALLREDUCE_RD_MAX_BYTES", "ALLREDUCE_RAB_MIN_BYTES",
            "BCAST_PIPELINE_MIN_BYTES", "ALLTOALL_BRUCK_MAX_BLOCK",
            "COMMIT_COUNTERS", "clear_commit_cache", "clear_nic_cache",

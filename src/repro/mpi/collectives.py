@@ -6,7 +6,7 @@ callbacks — the natural shape for a tick-driven fabric, and exactly how
 tree collectives overlap under loss (a subtree whose link is clean makes
 progress while another subtree retransmits).  The nonblocking entry
 points (``ibcast`` / ``ireduce`` / ``iallreduce`` / ``ialltoall`` /
-``ialltoallv`` / ``ibarrier``) register the plan with the communicator
+``ialltoallv`` / ``ibarrier`` / ``icomm3``) register the plan with the communicator
 and return a :class:`CollRequest` handle supporting ``test``/``wait`` and
 mixing freely with p2p handles in ``waitall``; the blocking wrappers keep
 their historical signatures by posting and waiting.
@@ -37,6 +37,8 @@ Algorithms (selected per message size when ``algorithm="auto"``):
   alltoall(v) "bruck"  store-and-forward, ⌈log₂ n⌉ rounds of ⌈n/2⌉
                        coalesced blocks (message-count optimal)
               "pairwise"  direct exchange, n−1 messages per rank
+  comm3       NPB MG's ghost-layer exchange of 3-D blocks, axis by axis,
+              each face one typed message the receiving NIC unpacks
 
 **Large-message fast path.**  Any plan message larger than the eager
 staging slot is transparently *segmented*: the payload travels as
@@ -1098,11 +1100,119 @@ class AlltoallBruckPlan(_ExchangeResult, Plan):
                    if (1 << i) < self.n]
 
 
+# ------------------------------------------------------------------ comm3
+def _plane_offset(strides: Sequence[int], axis: int, i: int) -> int:
+    """Byte offset, in a C-order ``(n3, n2, n1)`` array of ``strides``, of
+    plane ``i`` of ``axis`` (0 is i1) where comm3's face type starts: whole
+    along the axes exchanged before, past the ghosts of those after."""
+    return i * strides[2 - axis] + sum(strides[2 - b]
+                                       for b in range(axis + 1, 3))
+
+
+class Comm3Plan(Plan):
+    """NPB MG's ``comm3``: the ghost-layer exchange of one 3-D block a rank
+    on a periodic process grid, axis by axis as ``mg.f`` orders it.
+
+    Rank ``r`` holds a C-order ``(n3, n2, n1)`` array, i1 fastest as in
+    Fortran's ``u(n1, n2, n3)``, whose outer layer on every axis is ghost.
+    On axis ``a`` a rank posts both receives, then sends its boundary plane
+    ``i = n-2`` to its +1 neighbour and ``i = 1`` to its −1 neighbour with
+    the committed face datatype ``faces[a]``; ghost ``i = 0`` fills from
+    the −1 neighbour and ``i = n-1`` from the +1 neighbour.  It starts axis
+    ``a+1`` only when all four requests of axis ``a`` completed, so later
+    faces carry the ghosts earlier axes filled: that is how edges and
+    corners arrive.  Each face is one typed message between views of the
+    arrays at the planes' displacements: at or above the eager threshold a
+    rendezvous whose receiving NIC unpacks it into the ghost plane in
+    place, below it an eager message the host unpacks.  Faces are never
+    cut into ``__coll_seg__`` segments.
+
+    The arrays are pooled by reference, so the exchange lands in the
+    caller's arrays; a checkpoint restores them as copies, which
+    ``result`` then holds.
+    """
+
+    NAME = "comm3"
+
+    def __init__(self, comm, pid, tag_base, arrays, dims, faces):
+        super().__init__(comm, pid, tag_base)
+        self.dims = [int(d) for d in dims]
+        self.faces = [int(f) for f in faces]
+        self.shape = list(arrays[0].shape)
+        self.strides = list(arrays[0].strides)
+        self.bids = [self._adopt(a) for a in arrays]
+        self.axis = [0] * comm.n_ranks    # per rank, the axis in progress
+        self.left = [0] * comm.n_ranks    # and its requests not completed
+        self.request.rounds = 3
+
+    def _neighbours(self, r: int, axis: int) -> Tuple[int, int]:
+        """The −1 and +1 neighbours of rank ``r`` on ``axis``; ranks run
+        over the grid with axis 1 fastest, as NPB numbers them."""
+        d1, d2, _ = self.dims
+        coords = [r % d1, r // d1 % d2, r // (d1 * d2)]
+
+        def rank(step: int) -> int:
+            c = list(coords)
+            c[axis] = (c[axis] + step) % self.dims[axis]
+            return c[0] + d1 * (c[1] + d2 * c[2])
+        return rank(-1), rank(+1)
+
+    def start(self) -> None:
+        for r in range(self.comm.n_ranks):
+            self._post_axis(r)
+
+    def _post_axis(self, r: int) -> None:
+        a = self.axis[r]
+        n = self.shape[2 - a]
+        face = self.faces[a]
+        ext = self.comm.registry.mem_bytes(face)
+        flat = self._buf(self.bids[r]).reshape(-1).view(np.uint8)
+        lo, hi = self._neighbours(r, a)
+        self.left[r] = 4
+        # tag 2a carries planes sent up (to the +1 neighbour), 2a+1 down
+        for d, (src, ghost) in enumerate(((lo, 0), (hi, n - 1))):
+            off = _plane_offset(self.strides, a, ghost)
+            req = self.comm.irecv(r, flat[off:off + ext], source=src,
+                                  tag=self.tag_base + 2 * a + d,
+                                  buf_id=self.bids[r])
+            self._track(req, ("cr", r, a, d))
+        for d, (dest, plane) in enumerate(((hi, n - 2), (lo, 1))):
+            off = _plane_offset(self.strides, a, plane)
+            req = self.comm.isend(r, dest, flat[off:off + ext],
+                                  tag=self.tag_base + 2 * a + d,
+                                  datatype=face)
+            self.request.bytes_wire += req.nbytes
+            self._track(req, ("cs", r, a, d))
+
+    def on_step(self, key, req) -> None:
+        r = key[1]
+        self.left[r] -= 1
+        if self.left[r] == 0:
+            self.axis[r] += 1
+            if self.axis[r] < 3:
+                self._post_axis(r)
+
+    def result(self):
+        return [self._buf(b) for b in self.bids]
+
+    def _snap_state(self):
+        return dict(dims=list(self.dims), faces=list(self.faces),
+                    shape=list(self.shape), strides=list(self.strides),
+                    bids=list(self.bids), axis=list(self.axis),
+                    left=list(self.left))
+
+    def _restore_state(self, s):
+        self.dims, self.faces = list(s["dims"]), list(s["faces"])
+        self.shape, self.strides = list(s["shape"]), list(s["strides"])
+        self.bids = list(s["bids"])
+        self.axis, self.left = list(s["axis"]), list(s["left"])
+
+
 PLAN_TYPES: Dict[str, type] = {
     p.NAME: p for p in (BcastPlan, BcastPipelinedPlan, ReducePlan,
                         AllreduceTreePlan, AllreduceRDPlan,
                         AllreduceRabenseifnerPlan, AllreduceLinearPlan,
-                        AlltoallPairwisePlan, AlltoallBruckPlan)
+                        AlltoallPairwisePlan, AlltoallBruckPlan, Comm3Plan)
 }
 
 
@@ -1215,6 +1325,37 @@ def ibarrier(comm: Communicator) -> CollRequest:
                       algorithm="rd")
 
 
+def icomm3(comm: Communicator, arrays: Sequence[np.ndarray],
+           dims: Sequence[int], faces: Sequence[int]) -> CollRequest:
+    """Nonblocking NPB MG ``comm3`` (:class:`Comm3Plan`): the ghost planes,
+    edges and corners of every rank's ``arrays[r]`` filled in place from
+    its neighbours on the periodic process grid ``dims`` (d1, d2, d3; rank
+    ``c1 + d1·(c2 + d2·c3)``).  ``faces`` are the committed datatype ids of
+    the axis-1, -2 and -3 faces; ``result`` is the array list."""
+    dims = [int(d) for d in dims]
+    if len(dims) != 3 or min(dims) < 2:
+        raise ValueError(f"comm3 needs a 3-D process grid with at least 2 "
+                         f"ranks on every axis, not {dims}")
+    if dims[0] * dims[1] * dims[2] != comm.n_ranks:
+        raise ValueError(f"process grid {dims} does not hold the "
+                         f"communicator's {comm.n_ranks} ranks")
+    if len(arrays) != comm.n_ranks or len(faces) != 3:
+        raise ValueError("comm3 needs one array a rank and three faces")
+    a0 = arrays[0]
+    if a0.ndim != 3 or min(a0.shape) < 3 or not all(
+            isinstance(a, np.ndarray) and a.flags["C_CONTIGUOUS"]
+            and a.shape == a0.shape and a.dtype == a0.dtype
+            for a in arrays):
+        raise ValueError("comm3 needs C-contiguous 3-D arrays of one shape "
+                         "and dtype, at least 3 along every axis")
+    for axis, face in enumerate(faces):
+        top = _plane_offset(a0.strides, axis, a0.shape[2 - axis] - 1)
+        if top + comm.registry.mem_bytes(face) > a0.nbytes:
+            raise ValueError(f"face datatype {face} of axis {axis + 1} "
+                             f"reaches past the array from its ghost plane")
+    return _start(comm, Comm3Plan, arrays, dims, faces)
+
+
 # ------------------------------------------------------- blocking wrappers
 def bcast(comm: Communicator, bufs: Sequence[np.ndarray], root: int = 0,
           max_ticks: int = 200_000, algorithm: str = "auto") -> None:
@@ -1267,3 +1408,12 @@ def alltoallv(comm: Communicator,
 def barrier(comm: Communicator, max_ticks: int = 200_000) -> None:
     """No rank leaves before every rank arrived."""
     comm.wait(ibarrier(comm), max_ticks=max_ticks)
+
+
+def comm3(comm: Communicator, arrays: Sequence[np.ndarray],
+          dims: Sequence[int], faces: Sequence[int],
+          max_ticks: int = 200_000) -> List[np.ndarray]:
+    """NPB MG ``comm3`` on every rank's array, in place; returns them."""
+    req = icomm3(comm, arrays, dims, faces)
+    comm.wait(req, max_ticks=max_ticks)
+    return req.result

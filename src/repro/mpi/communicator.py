@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import apps
@@ -235,6 +236,11 @@ class Communicator:
 
         # one NIC (and one compiled datapath) shared by every rank
         self.nic = nic
+        # on the device once, here: the offsets each datatype with holes
+        # names, which a FIN of such a type reads from its slot
+        self.named = {i: jnp.asarray(self.registry.named(i))
+                      for i in range(len(self.registry))
+                      if not self.registry.dense(i)}
         self.pool = BufferPool()
         self._plans: Dict[int, "object"] = {}
         self._next_plan_id = 0
@@ -242,7 +248,7 @@ class Communicator:
         self.nodes: List[Node] = []
         for r in range(n_ranks):
             engine = MpiHostEngine(r, self.registry, self.params,
-                                   pool=self.pool)
+                                   pool=self.pool, named=self.named)
             node = Node(f"rank{r}", macs[r], nic=self.nic,
                         engines=[engine])
             engine.attach(node)
@@ -268,7 +274,7 @@ class Communicator:
         self.engines = []
         for r, node in enumerate(self.nodes):
             engine = MpiHostEngine(r, self.registry, self.params,
-                                   pool=self.pool)
+                                   pool=self.pool, named=self.named)
             node.reset(engines=[engine])
             engine.attach(node)
             self.engines.append(engine)

@@ -58,6 +58,10 @@ class DatatypeRegistry:
         self._committed: List[ddtlib.CommittedDDT] = []
         self._names: List[str] = []
         self._frozen = False
+        # per dtype id, computed on first use: the extent's byte mask and
+        # the offsets it names (a FIN of a large type must not rebuild them)
+        self._masks: Dict[int, np.ndarray] = {}
+        self._named: Dict[int, np.ndarray] = {}
 
     def __len__(self) -> int:
         return len(self._committed)
@@ -101,7 +105,25 @@ class DatatypeRegistry:
 
     def mem_mask(self, dtype_id: int) -> np.ndarray:
         """(mem_bytes,) bool — bytes the datatype actually writes."""
-        return self._committed[dtype_id].mem_to_msg >= 0
+        mask = self._masks.get(dtype_id)
+        if mask is None:
+            mask = self._masks[dtype_id] = \
+                self._committed[dtype_id].mem_to_msg >= 0
+        return mask
+
+    def named(self, dtype_id: int) -> np.ndarray:
+        """Ascending int32 offsets of the extent's bytes the datatype
+        writes: where a receive lands, the holes keeping the buffer's
+        contents."""
+        offs = self._named.get(dtype_id)
+        if offs is None:
+            offs = self._named[dtype_id] = np.flatnonzero(
+                self.mem_mask(dtype_id)).astype(np.int32)
+        return offs
+
+    def dense(self, dtype_id: int) -> bool:
+        """True when the datatype names every byte of its extent."""
+        return self.named(dtype_id).size == self.mem_bytes(dtype_id)
 
     # ------------------------------------------------------- device tables
     @property

@@ -17,8 +17,9 @@ and implements the host half of the messaging layer:
     count*) → SLMP data to the NIC *DDT-unpack* context — the receive-side
     datatype processing runs entirely on the NIC, scattering payload bytes
     through the committed index map into the posted region — → FIN
-    completes the receive with a masked copy-out (no host unpack on the
-    critical path).
+    completes the receive by copying out the bytes the datatype names: the
+    whole slot for a dense type, a device gather of the named offsets for
+    one with holes (no host unpack on the critical path).
 
 **Credit-managed rendezvous.** Receive slots are *credits*: the receiver
 owns ``n_rdv_slots`` leases, debits one per CTS, and returns it the
@@ -173,6 +174,10 @@ def _u8view(buf: np.ndarray) -> np.ndarray:
     return buf.reshape(-1).view(np.uint8)
 
 
+def _address(buf: np.ndarray) -> int:
+    return buf.__array_interface__["data"][0]
+
+
 def _env_snap(e: _Envelope) -> tuple:
     return (e.kind, dataclasses.astuple(e.ctl),
             None if e.payload is None else e.payload.copy())
@@ -186,10 +191,13 @@ def _env_restore(t: tuple) -> _Envelope:
 
 class MpiHostEngine(HostEngine):
     def __init__(self, rank: int, registry: DatatypeRegistry,
-                 params: MpiParams, pool=None):
+                 params: MpiParams, pool=None, named=None):
         self.rank = rank
         self.registry = registry
         self.p = params
+        # dtype id -> device int32 offsets it names, for every registered
+        # datatype with holes (built once by the Communicator)
+        self._named = named or {}
         self.pool = pool                        # BufferPool (checkpointing)
         self._node = None                       # set by attach()
         self.ctl = wire.CtlEndpoint(rank, list(params.macs),
@@ -597,14 +605,15 @@ class MpiHostEngine(HostEngine):
         phys = fin.slot % self.p.n_rdv_slots
         if req is not None:
             base = self.p.rdv_base + phys * self.p.rdv_region_bytes
-            mem_bytes = self.registry.mem_bytes(rts.dtype_id)
-            window = np.array(self._node.read_host(base, mem_bytes),
-                              np.uint8)
-            mask = self.registry.mem_mask(rts.dtype_id)
-            view = _u8view(req.buf)
+            dtype_id = rts.dtype_id
+            view = _u8view(req.buf)[:self.registry.mem_bytes(dtype_id)]
             # the NIC already unpacked: copy only the bytes the datatype
             # wrote (holes keep the buffer's contents — MPI unpack)
-            view[:mem_bytes][mask] = window[mask]
+            if self.registry.dense(dtype_id):
+                view[:] = self._node.read_host(base, view.size)
+            else:
+                view[self.registry.named(dtype_id)] = self._node.read_named(
+                    base, self._named[dtype_id])
         # disarm and recycle the slot immediately: late duplicates of this
         # (or any earlier) occupant no longer match the expect table
         self._node.write_expect(phys, 0)
@@ -627,7 +636,15 @@ class MpiHostEngine(HostEngine):
             buf = None
         elif req.buf_id is not None and self.pool is not None \
                 and self.pool.has(req.buf_id):
-            buf = ("pool", req.buf_id)
+            whole = self.pool.get(req.buf_id)
+            if req.buf is whole:
+                buf = ("pool", req.buf_id)
+            else:
+                # a byte range of a pooled array: a plan receiving into
+                # part of a caller's array
+                off = _address(req.buf) - _address(whole)
+                assert 0 <= off and off + req.buf.nbytes <= whole.nbytes
+                buf = ("pool_bytes", req.buf_id, off, req.buf.nbytes)
         else:
             # aliasing into user arrays cannot survive a fresh object
             # graph: the restored request owns a copy (read results off
@@ -641,11 +658,14 @@ class MpiHostEngine(HostEngine):
         buf = None
         buf_id = None
         if s["buf"] is not None:
-            how, val = s["buf"]
-            if how == "pool":
+            how, val = s["buf"][:2]
+            if how in ("pool", "pool_bytes"):
                 assert self.pool is not None, \
                     "pool-bound request needs a BufferPool to restore into"
                 buf, buf_id = self.pool.get(val), val
+                if how == "pool_bytes":
+                    off, nbytes = s["buf"][2:]
+                    buf = _u8view(buf)[off:off + nbytes]
             else:
                 buf = np.array(val)
         req = Request(s["kind"], buf=buf, source=s["source"], tag=s["tag"])

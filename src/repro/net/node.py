@@ -306,6 +306,10 @@ class Node:
     def read_host(self, base: int, nbytes: int) -> np.ndarray:
         return self.nic.read_host(self.state, base, nbytes)
 
+    def read_named(self, base: int, offsets) -> np.ndarray:
+        """The window's bytes at ``base + offsets`` (a device int32 array)."""
+        return self.nic.read_named(self.state, base, offsets)
+
     def write_expect(self, idx: int, msg_id: int) -> None:
         """Host MMIO write into the NIC's expected-msg_id slot table."""
         self.state = self.nic.write_expect(self.state, idx, msg_id)
